@@ -91,8 +91,8 @@ def bench_figures(size_mib: int) -> None:
 
 
 def bench_kernels(size_mib: int) -> None:
-    """OnPair device-codec throughput (jit ref path; Pallas validated in
-    interpret mode by tests — interpret timing is not meaningful)."""
+    """OnPair device-codec throughput (jit ref path; on a CPU the Pallas
+    kernels run interpreted, and interpreted timing is not meaningful)."""
     import numpy as np
 
     from benchmarks.common import dataset

@@ -145,7 +145,7 @@ def store_ingest_bench(size_mib: int, seed: int = 0,
 
     # pallas-backend encode row, reported alongside the numpy rows but never
     # baseline-gated: it is absent on REPRO_NO_JAX hosts (the CI smoke), and
-    # this container runs the kernel in interpret mode, so n stays small
+    # a CPU host runs the kernel interpreted, so n stays small
     try:
         if os.environ.get("REPRO_NO_JAX"):
             raise ImportError("REPRO_NO_JAX is set")

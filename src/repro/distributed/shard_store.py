@@ -18,7 +18,8 @@ in-process; testing and single-host serving) and
 ``repro.net.router.DistributedStringStore`` (every shard behind its own
 RPC server process).
 
-Pure numpy — no jax required on either the writer or the reader host.
+Pure numpy on the writer; a reader whose shards decode on the jax backend
+spreads them over the host's devices (:func:`_shard_device`).
 """
 
 from __future__ import annotations
@@ -159,22 +160,34 @@ def open_shard(dir_path: str, shard: int, mmap: bool = True,
     ``writable=True`` opens the shard as a :class:`MutableStringStore` so it
     accepts appends against the shared frozen dictionary; once a writable
     shard has been saved or compacted it owns a *versioned* layout (and its
-    own dictionary generation), which takes precedence on reopen."""
+    own dictionary generation), which takes precedence on reopen. A shard
+    on the jax backend keeps its tables on :func:`_shard_device`."""
     shard_dir = os.path.join(dir_path, f"shard-{shard:04d}")
-    if CompressedStringStore._resolve_current(shard_dir) != shard_dir:
-        if not writable:  # read-only open of the shard's current generation
-            return CompressedStringStore.open(shard_dir, mmap=mmap,
-                                              **overrides)
-        return MutableStringStore.open(shard_dir, mmap=mmap, **overrides)
-    if source is None:
-        art = DictArtifact.load(os.path.join(dir_path, DICT_FILE), mmap=mmap)
-        source = (art, registry.codec_from_artifact(art))
     store_cls = MutableStringStore if writable else CompressedStringStore
-    store = store_cls.open_corpus_dir(shard_dir, source, mmap=mmap,
-                                      **overrides)
-    if writable:
-        store._dir = shard_dir  # compact() rewrites land in the shard dir
+    if CompressedStringStore._resolve_current(shard_dir) != shard_dir:
+        # a read-only open serves the shard's current generation
+        store = store_cls.open(shard_dir, mmap=mmap, **overrides)
+    else:
+        if source is None:
+            art = DictArtifact.load(os.path.join(dir_path, DICT_FILE),
+                                    mmap=mmap)
+            source = (art, registry.codec_from_artifact(art))
+        store = store_cls.open_corpus_dir(shard_dir, source, mmap=mmap,
+                                          **overrides)
+        if writable:
+            store._dir = shard_dir  # compact() rewrites land in the shard dir
+    if store.backend == "jax":
+        store._place(_shard_device(shard))
     return store
+
+
+def _shard_device(shard: int):
+    """The device that serves shard ``shard``: shards spread round-robin
+    over the host's JAX devices (all on one when it has one)."""
+    import jax
+
+    devices = jax.devices()
+    return devices[shard % len(devices)]
 
 
 class ShardRouter:

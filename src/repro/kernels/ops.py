@@ -8,6 +8,7 @@ against repro.kernels.ref and the Python reference implementations.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -78,12 +79,14 @@ def pack_token_matrix(token_lists: list[np.ndarray], pad_tokens: int | None = No
 class OnPairDevice:
     """Device-side OnPair16 codec over a trained PackedDictionary."""
 
-    def __init__(self, dictionary: PackedDictionary):
+    def __init__(self, dictionary: PackedDictionary, device=None):
         if not dictionary.variant16:
             raise ValueError("device kernels target OnPair16 (<=16B entries); "
                              "unbounded OnPair stays on the host path")
         self.dictionary = dictionary
-        self.dd = DeviceDict.build(dictionary)
+        # the tables live on ``device`` (JAX's default when None); every
+        # kernel call runs where they are
+        self.dd = DeviceDict.build(dictionary, device)
         # Bucketed-encode state: every launch uses a static
         # (encode_pad_batch, cap + 16) shape drawn from encode_len_caps, so
         # the number of compiled encode traces is bounded by the bucket set
@@ -93,6 +96,15 @@ class OnPairDevice:
         #: every (B, L) data shape handed to the encode kernels — tests assert
         #: this stays bounded under mixed-length workloads
         self.encode_shapes: set[tuple[int, int]] = set()
+
+    @property
+    def device(self):
+        """The JAX device holding this codec's tables."""
+        return next(iter(self.dd.mat16.devices()))
+
+    def place(self, device) -> None:
+        """Move the tables to ``device``; later calls run there."""
+        self.dd = jax.device_put(self.dd, device)
 
     @classmethod
     def from_artifact(cls, artifact) -> "OnPairDevice":
@@ -191,7 +203,6 @@ class OnPairDevice:
                 max_out, tile=tile)
         else:
             from repro.kernels.ref import decode_ref
-            import jax
             out, out_len = jax.jit(decode_ref, static_argnames=("max_out",))(
                 jnp.asarray(padded), jnp.int32(n), self.dd.mat16, self.dd.lens,
                 max_out=max_out)
@@ -230,6 +241,8 @@ class OnPairDevice:
         and runs the per-string decode kernel once; max_out = 16 * T is exact
         for OnPair16 (every entry <= 16 B). Returns only the real rows.
         """
+        if not token_lists:
+            return []
         tokens, n_tokens = pack_token_matrix(token_lists, pad_tokens, pad_batch)
         max_out = 16 * tokens.shape[1]
         out = self.decode_batch(tokens, n_tokens, max_out, use_pallas=use_pallas)

@@ -21,7 +21,6 @@ import numpy as np
 from repro.core.packed import PackedDictionary
 
 
-
 def mix32(x: jnp.ndarray) -> jnp.ndarray:
     """murmur3-style finaliser; must match repro.core.packed.mix32."""
     x = x.astype(jnp.uint32)
@@ -36,21 +35,20 @@ def hash_key(lo: jnp.ndarray, hi: jnp.ndarray, length: jnp.ndarray) -> jnp.ndarr
     return mix32(lo ^ mix32(hi ^ mix32(length.astype(jnp.uint32))))
 
 
-def ctz32(x: jnp.ndarray) -> jnp.ndarray:
-    """Count trailing zeros (32 for x == 0) via popcount((x & -x) - 1)."""
-    x = x.astype(jnp.uint32)
-    low = x & (jnp.uint32(0) - x)          # isolate lowest set bit
-    return jax.lax.population_count(low - jnp.uint32(1)).astype(jnp.int32)
+def low_zero_bytes(d: jnp.ndarray) -> jnp.ndarray:
+    """Zero low-order bytes of u32 ``d`` (4 if d == 0), by byte-mask
+    compares: the scalar unit of a TPU core has no population count."""
+    d = d.astype(jnp.uint32)
+    return sum(((d & jnp.uint32(m)) == 0).astype(jnp.int32)
+               for m in (0xFF, 0xFFFF, 0xFFFFFF, 0xFFFFFFFF))
 
 
 def shared_prefix_bytes(lo1, hi1, lo2, hi2) -> jnp.ndarray:
     """Algorithm 2 on (lo, hi) u32 pairs: # of matching low-order bytes."""
     dlo = (lo1 ^ lo2).astype(jnp.uint32)
     dhi = (hi1 ^ hi2).astype(jnp.uint32)
-    tz_lo = ctz32(dlo) >> 3          # 0..4 (4 if dlo == 0)
-    tz_hi = ctz32(dhi) >> 3          # 0..4
-    return jnp.where(dlo != 0, jnp.minimum(tz_lo, 4),
-                     4 + jnp.minimum(tz_hi, 4)).astype(jnp.int32)
+    return jnp.where(dlo != 0, low_zero_bytes(dlo),
+                     4 + low_zero_bytes(dhi)).astype(jnp.int32)
 
 
 @dataclass(frozen=True)
@@ -82,18 +80,22 @@ class DeviceDict:
     max_bucket: int
 
     @staticmethod
-    def build(d: PackedDictionary) -> "DeviceDict":
+    def build(d: PackedDictionary, device=None) -> "DeviceDict":
+        """Upload ``d``'s tables to ``device`` (JAX's default when None)."""
+        def put(x):
+            return jax.device_put(np.asarray(x), device)
+
         return DeviceDict(
-            mat16=jnp.asarray(d.mat16.astype(np.int32)),
-            lens=jnp.asarray(d.lens.astype(np.int32)),
-            s_lo=jnp.asarray(d.s_lo), s_hi=jnp.asarray(d.s_hi),
-            s_len=jnp.asarray(d.s_len), s_tok=jnp.asarray(d.s_tok),
-            p_lo=jnp.asarray(d.p_lo), p_hi=jnp.asarray(d.p_hi),
-            p_len=jnp.asarray(d.p_len), p_bucket=jnp.asarray(d.p_bucket),
-            bucket_start=jnp.asarray(d.bucket_start),
-            bucket_size=jnp.asarray(d.bucket_size),
-            suf_lo=jnp.asarray(d.suf_lo), suf_hi=jnp.asarray(d.suf_hi),
-            suf_len=jnp.asarray(d.suf_len), suf_tok=jnp.asarray(d.suf_tok),
+            mat16=put(d.mat16.astype(np.int32)),
+            lens=put(d.lens.astype(np.int32)),
+            s_lo=put(d.s_lo), s_hi=put(d.s_hi),
+            s_len=put(d.s_len), s_tok=put(d.s_tok),
+            p_lo=put(d.p_lo), p_hi=put(d.p_hi),
+            p_len=put(d.p_len), p_bucket=put(d.p_bucket),
+            bucket_start=put(d.bucket_start),
+            bucket_size=put(d.bucket_size),
+            suf_lo=put(d.suf_lo), suf_hi=put(d.suf_hi),
+            suf_len=put(d.suf_len), suf_tok=put(d.suf_tok),
             s_probe_max=int(d.s_probe_max), p_probe_max=int(d.p_probe_max),
             max_bucket=int(max(1, d.max_bucket_size)),
         )
@@ -147,19 +149,22 @@ def decode_batch_ref(tokens: jnp.ndarray, n_tokens: jnp.ndarray,
 
 
 # ============================================================ encode oracle
-def _pack_window(window: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Pack 8 byte-values (int32[8]) little-endian into (lo, hi) u32."""
-    w = window.astype(jnp.uint32)
+def _pack_window(window) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Pack 8 byte-values (an int32[8], or eight int32 scalars) little-endian
+    into (lo, hi) u32."""
+    w = [window[k].astype(jnp.uint32) for k in range(8)]
     lo = w[0] | (w[1] << 8) | (w[2] << 16) | (w[3] << 24)
     hi = w[4] | (w[5] << 8) | (w[6] << 16) | (w[7] << 24)
     return lo, hi
 
 
-def _probe_table(lo, hi, length, t_lo, t_hi, t_len, t_payload, probe_max: int):
+def _probe_table(at, lo, hi, length, t_lo, t_hi, t_len, t_payload,
+                 probe_max: int):
     """Linear-probe an open-addressing table; returns payload or -1.
 
     Probing stops at the first empty slot (len == 0) — matching insertion —
     and is bounded by the build-time max probe count, so the loop is static.
+    ``at(table, i)`` reads one table element (see :func:`lpm_search`).
     """
     size = t_lo.shape[0]
     mask = jnp.uint32(size - 1)
@@ -167,50 +172,51 @@ def _probe_table(lo, hi, length, t_lo, t_hi, t_len, t_payload, probe_max: int):
 
     def body(i, carry):
         found, done = carry
-        slot = (slot0 + i.astype(jnp.uint32)) & mask
-        sl = t_len[slot]
-        hit = (sl == length) & (t_lo[slot] == lo) & (t_hi[slot] == hi)
+        slot = ((slot0 + i.astype(jnp.uint32)) & mask).astype(jnp.int32)
+        sl = at(t_len, slot)
+        hit = (sl == length) & (at(t_lo, slot) == lo) & (at(t_hi, slot) == hi)
         empty = sl == 0
-        found = jnp.where(~done & hit, t_payload[slot], found)
+        found = jnp.where(~done & hit, at(t_payload, slot), found)
         done = done | hit | empty
         return found, done
 
     found, _ = jax.lax.fori_loop(
-        0, probe_max, lambda i, c: body(i, c),
-        (jnp.int32(-1), jnp.bool_(False)))
+        0, probe_max, body, (jnp.int32(-1), jnp.bool_(False)))
     return found
 
 
-def _lpm_search_ref(data_row: jnp.ndarray, pos: jnp.ndarray, str_len: jnp.ndarray,
-                    dd: DeviceDict) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Algorithm 1 at one position; data_row is int32[L+16] zero-padded.
+def lpm_search(window, at, pos: jnp.ndarray, str_len: jnp.ndarray,
+               dd: DeviceDict) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Algorithm 1 at one position: (token_id, match_len).
 
-    Returns (token_id, match_len). Requires all 256 single bytes present.
+    The oracle and the encode kernel share this walk and differ only in
+    how they read memory: ``window(p)`` packs the string's 8 bytes at ``p``
+    into (lo, hi) u32, and ``at(table, i)`` reads element ``i`` of one of
+    ``dd``'s LPM tables. Requires all 256 single bytes present.
     """
     rem = str_len - pos
-    w1 = jax.lax.dynamic_slice(data_row, (pos,), (8,))
-    lo1, hi1 = _pack_window(w1)
+    lo1, hi1 = window(pos)
 
     # ---- long tier ----
-    w2 = jax.lax.dynamic_slice(data_row, (pos + 8,), (8,))
-    lo2, hi2 = _pack_window(w2)
-    bucket = _probe_table(lo1, hi1, jnp.int32(8), dd.p_lo, dd.p_hi, dd.p_len,
-                          dd.p_bucket, dd.p_probe_max)
+    lo2, hi2 = window(pos + 8)
+    bucket = _probe_table(at, lo1, hi1, jnp.int32(8), dd.p_lo, dd.p_hi,
+                          dd.p_len, dd.p_bucket, dd.p_probe_max)
     use_long = (rem > 8) & (bucket >= 0)
     b = jnp.maximum(bucket, 0)
-    start = dd.bucket_start[b]
-    size = jnp.where(use_long, dd.bucket_size[b], 0)
+    start = at(dd.bucket_start, b)
+    size = jnp.where(use_long, at(dd.bucket_size, b), 0)
 
     def bucket_body(k, carry):
         tok, mlen, done = carry
         i = start + k
         in_range = k < size
-        s_len = dd.suf_len[i]
+        s_len = at(dd.suf_len, i)
         fits = s_len <= (rem - 8)
-        shared = shared_prefix_bytes(lo2, hi2, dd.suf_lo[i], dd.suf_hi[i])
+        shared = shared_prefix_bytes(lo2, hi2, at(dd.suf_lo, i),
+                                     at(dd.suf_hi, i))
         # OnPair16: suffixes are <= 8 B so the packed compare is exact.
         hit = in_range & fits & (shared >= s_len) & ~done
-        tok = jnp.where(hit, dd.suf_tok[i], tok)
+        tok = jnp.where(hit, at(dd.suf_tok, i), tok)
         mlen = jnp.where(hit, 8 + s_len, mlen)
         done = done | hit | ~in_range
         return tok, mlen, done
@@ -235,7 +241,7 @@ def _lpm_search_ref(data_row: jnp.ndarray, pos: jnp.ndarray, str_len: jnp.ndarra
         ok = length >= 1
         lo = lo1 & byte_mask(length)
         hi = hi1 & byte_mask(length - 4)
-        cand = _probe_table(lo, hi, length, dd.s_lo, dd.s_hi, dd.s_len,
+        cand = _probe_table(at, lo, hi, length, dd.s_lo, dd.s_hi, dd.s_len,
                             dd.s_tok, dd.s_probe_max)
         hit = ok & (cand >= 0) & ~done
         tok = jnp.where(hit, cand, tok)
@@ -249,6 +255,16 @@ def _lpm_search_ref(data_row: jnp.ndarray, pos: jnp.ndarray, str_len: jnp.ndarra
     tok = jnp.where(long_found, ltok, stok)
     mlen = jnp.where(long_found, lmlen, smlen)
     return tok.astype(jnp.int32), mlen.astype(jnp.int32)
+
+
+def _lpm_search_ref(data_row: jnp.ndarray, pos: jnp.ndarray,
+                    str_len: jnp.ndarray,
+                    dd: DeviceDict) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`lpm_search` over arrays; data_row is int32[L+16] zero-padded."""
+    def window(p):
+        return _pack_window(jax.lax.dynamic_slice(data_row, (p,), (8,)))
+
+    return lpm_search(window, lambda t, i: t[i], pos, str_len, dd)
 
 
 def encode_ref(data_row: jnp.ndarray, str_len: jnp.ndarray,

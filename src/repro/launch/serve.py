@@ -11,8 +11,9 @@ materialisation is a batched store multiget through the Pallas decoder.
       --doc-ids 3 17 4242 --max-new 8
 
 ``--shard-server`` flips the launcher into its other role: a per-shard RPC
-server process for the multi-process serving tier (``repro.net``) — no LM,
-and no jax needed on the host (heavy imports only happen on the LM path):
+server process for the multi-process serving tier (``repro.net``) — no LM
+(heavy imports only happen on the LM path). Both roles keep JAX's
+persistent compilation cache (:mod:`repro.kernels.cache`):
 
   PYTHONPATH=src python -m repro.launch.serve \
       --shard-server /data/corpus/shard-0002 --port 9102
@@ -63,9 +64,11 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args()
+    from repro.kernels.cache import use_compile_cache
+    use_compile_cache()
 
     if args.shard_server:
-        # RPC-server role: stdlib + numpy only — never pull in jax/the LM
+        # RPC-server role: the store alone — never pull in the LM
         from repro.net.shard_server import run
         run(args.shard_server, host=args.host, port=args.port,
             read_only=args.read_only, metrics_port=args.metrics_port)

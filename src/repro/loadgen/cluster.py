@@ -7,8 +7,11 @@ and understates every latency. :class:`LocalCluster` launches one
 replicas), waits on each ``SHARD_SERVER_READY`` announce line, records
 replica addresses into the cluster manifest (so the client's replica
 autodiscovery wires read load-balancing on connect), and tears everything
-down on exit. Children run ``REPRO_NO_JAX=1`` — serving needs numpy only,
-and skipping the jax import keeps spawn latency off the measurement.
+down on exit. Children run ``REPRO_NO_JAX=1``: a chip belongs to one
+process, so several shard processes on one host serve from numpy, and
+skipping the jax import keeps spawn latency off the measurement. The
+spawner itself never initialises JAX (``build_demo_corpus`` builds with
+``backend="numpy"``).
 """
 
 from __future__ import annotations
@@ -149,7 +152,9 @@ def build_demo_corpus(dir_path: str, n_shards: int = 2,
             bounds = json.load(fh)["bounds"]
         return bounds[-1][1]
     strings = load_dataset(dataset, target_mib << 20, seed=seed)
-    store = CompressedStringStore.build(strings, seed=seed)
+    # training and compressing need no device: stay off JAX, so a child
+    # started from this process can own the chip
+    store = CompressedStringStore.build(strings, seed=seed, backend="numpy")
     os.makedirs(dir_path, exist_ok=True)
     save_sharded(store, dir_path, n_shards)
     return len(strings)

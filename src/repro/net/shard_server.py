@@ -17,13 +17,19 @@ Run one per shard::
 
 With ``--port 0`` the kernel assigns a free port and the server prints
 ``SHARD_SERVER_READY port=<p> ...`` on stdout — spawners (the example, the
-rpc benchmark, tests) parse that line instead of racing for free ports.
+rpc benchmark, tests) parse that line instead of racing for free ports. The
+line names the decode backend the store resolved (``backend=jax`` or
+``backend=numpy``) and, for jax, the device it serves from
+(``platform=tpu kind="TPU v5 lite"``). On the jax backend this process owns
+the chip: nothing that spawns it may have initialised JAX itself.
 ``--read-only`` serves a replica: same directory, current versioned
 generation, appends and compaction refused — the hand-off target a router
 drains reads to while the primary rewrites itself.
 
 Set ``REPRO_NO_JAX=1`` in the environment to skip the jax import and serve
 on the numpy decode path (fast startup; what a CPU-only serving host runs).
+Otherwise ``main()`` turns on JAX's persistent compilation cache
+(:func:`repro.kernels.cache.use_compile_cache`).
 """
 
 from __future__ import annotations
@@ -36,6 +42,7 @@ import socket
 import socketserver
 import threading
 
+from repro.kernels.cache import use_compile_cache
 from repro.net import protocol as P
 from repro.obs import REGISTRY, TRACER, Counter, start_metrics_server
 from repro.store.mutable import MutableStringStore
@@ -344,11 +351,19 @@ def run(
     metrics = (start_metrics_server(port=metrics_port, host=host)
                if metrics_port is not None else None)
     if announce:
-        extra = f" metrics_port={metrics.port}" if metrics is not None else ""
+        snap = server.store.stats_snapshot()
+        device = snap.get("device")
+        # metrics_port= must stay right before dir=: spawners parse it there
+        extra = ""
+        if device:
+            extra = f" platform={device['platform']} kind={json.dumps(device['kind'])}"
+        if metrics is not None:
+            extra += f" metrics_port={metrics.port}"
         print(
             f"SHARD_SERVER_READY port={server.port} "
             f"n_strings={server.store.n_strings} "
-            f"writable={int(hasattr(server.store, 'extend'))}"
+            f"writable={int(hasattr(server.store, 'extend'))} "
+            f"backend={snap['backend']}"
             f"{extra} "
             f"dir={json.dumps(path)}",
             flush=True,
@@ -398,6 +413,7 @@ def main(argv=None) -> None:
         "stays under this target",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
     run(
         args.dir,
         host=args.host,

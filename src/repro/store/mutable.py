@@ -49,14 +49,8 @@ from repro.core.codec import Encoder
 from repro.core.index import SegmentIndex
 from repro.store.drift import DriftMonitor
 from repro.store.segment import SegmentedCorpus
-from repro.store.store import CompressedStringStore, write_json_atomic
-
-try:
-    if os.environ.get("REPRO_NO_JAX"):  # opt-out: numpy-only serving hosts
-        raise ImportError("REPRO_NO_JAX is set")
-    from repro.kernels.ops import OnPairDevice
-except Exception:  # pragma: no cover - container without jax
-    OnPairDevice = None
+from repro.store.store import (CompressedStringStore, device_codec,
+                               write_json_atomic)
 
 
 def _empty_corpus() -> CompressedCorpus:
@@ -115,7 +109,7 @@ class MutableStringStore(CompressedStringStore):
         if encode_backend not in ("numpy", "pallas"):
             raise ValueError(f"unknown encode_backend {encode_backend!r} "
                              "(one of 'numpy', 'pallas')")
-        if encode_backend == "pallas" and OnPairDevice is None:
+        if encode_backend == "pallas" and device_codec() is None:
             raise ValueError("encode_backend='pallas' unavailable: "
                              "jax not importable (or REPRO_NO_JAX set)")
         self.encode_backend = encode_backend
@@ -174,7 +168,8 @@ class MutableStringStore(CompressedStringStore):
         """
         if self.encode_backend == "pallas":
             if device is None:
-                device = OnPairDevice(compressor.dictionary)
+                device = device_codec()(compressor.dictionary,
+                                        self._placement)
             enc = Encoder(artifact, backend="pallas", codec=compressor,
                           device=device)
             enc.warm()
@@ -476,8 +471,7 @@ class MutableStringStore(CompressedStringStore):
         # artifact freeze and device-table upload both happen OUTSIDE the
         # lock — the locked swap only assigns
         new_artifact = new_comp.to_artifact()
-        new_device = (OnPairDevice(new_comp.dictionary)
-                      if self.backend == "jax" else None)
+        new_device = self._new_device(new_comp.dictionary)
         # tail encoder for the new generation — built (and, on the pallas
         # backend, AOT-warmed) outside the lock like the device tables
         new_encoder = self._make_encoder(new_artifact, new_comp, new_device)
@@ -533,9 +527,8 @@ class MutableStringStore(CompressedStringStore):
         self.segments = SegmentedCorpus.from_corpus(
             corpus, self.segments.strings_per_segment)
         self._set_bucket_caps(corpus.token_counts())
-        if self.backend == "jax":
-            self._device = (device if device is not None
-                            else OnPairDevice(self.dictionary))
+        self._device = (device if device is not None
+                        else self._new_device(self.dictionary))
         self._encoder = (encoder if encoder is not None else
                          self._make_encoder(self.artifact, self.compressor,
                                             self._device))
@@ -677,9 +670,10 @@ class MutableStringStore(CompressedStringStore):
         kw = {k: meta[k] for k in cls._STORE_KW}
         kw["train_ratio"] = meta.get("train_ratio")
         kw["drift_threshold"] = meta.get("drift_threshold", 0.2)
-        # saved on a jax host, reopened on a numpy-only one: fall back
-        eb = meta.get("encode_backend", "numpy")
-        kw["encode_backend"] = eb if OnPairDevice is not None else "numpy"
+        # a pallas encoder saved here raises in the constructor where the
+        # kernels are unavailable: reopen with encode_backend="numpy" to
+        # serve such a store from a numpy-only host
+        kw["encode_backend"] = meta.get("encode_backend", "numpy")
         kw["async_seal"] = meta.get("async_seal", True)
         kw.update(overrides)  # caller overrides beat every saved param
         store = cls(artifact, sealed, **kw)

@@ -30,6 +30,9 @@ class StoreStats:
         self.locate_hits = 0        # reverse lookups that found an id
         self.prefix_scans = 0       # scan_prefix calls
         self.jit_shapes: set[tuple[int, int]] = set()  # (B, T) decode shapes
+        #: seconds of the first decode batch of each jit shape: its compile
+        #: (or compile-cache load) plus one run
+        self.first_batch_s: dict[tuple[int, int], float] = {}
         # per-store instruments (snapshot() stays instance-scoped) registered
         # into the process registry, labelled by the resolved decode backend
         labels = {"backend": backend}
@@ -61,6 +64,7 @@ class StoreStats:
         self.decoded_bytes += nbytes
         self.decode_seconds += seconds
         if jitted:
+            self.first_batch_s.setdefault(shape, seconds)
             self.jit_shapes.add(shape)
 
     # ------------------------------------------------------------- reporting
@@ -82,6 +86,8 @@ class StoreStats:
                 self.decoded_strings / self.padded_rows, 4
             ) if self.padded_rows else 1.0,
             "jit_shapes": sorted(self.jit_shapes),
+            "first_batch_s": {f"{b}x{t}": round(sec, 4) for (b, t), sec
+                              in sorted(self.first_batch_s.items())},
             "decode_mib_s": round(
                 throughput_mib_s(self.decoded_bytes, self.decode_seconds), 2
             ) if self.decode_seconds else 0.0,

@@ -12,9 +12,12 @@ to their token streams, *length-bucketed* into a small set of static padded
 ``OnPairDevice.multiget_decode``). Pinning both the batch dim and the token
 dim to at most ``num_buckets`` bucket capacities keeps the number of
 jit-compiled decode shapes bounded (<= num_buckets, default 4) no matter the
-query mix. When JAX is unavailable — or the dictionary is unbounded OnPair,
-which the 16-byte-row kernel cannot decode — the store falls back to the
-vectorised numpy ``PackedDictionary.decode_tokens`` path.
+query mix. The store serves from the vectorised numpy
+``PackedDictionary.decode_tokens`` path when asked to (``backend="numpy"``),
+when the dictionary is unbounded OnPair (the 16-byte-row kernel cannot
+decode it), and under ``backend="auto"`` where jax is not installed or
+``REPRO_NO_JAX`` is set. ``stats_snapshot()`` names the backend it resolved
+and, for ``jax``, the device.
 """
 
 from __future__ import annotations
@@ -39,14 +42,22 @@ from repro.store.cache import LRUCache
 from repro.store.segment import SegmentedCorpus
 from repro.store.stats import StoreStats
 
-try:
-    if os.environ.get("REPRO_NO_JAX"):  # opt-out: numpy-only serving hosts
-        raise ImportError("REPRO_NO_JAX is set")
-    from repro.kernels.ops import OnPairDevice
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - container without jax
-    OnPairDevice = None
-    _HAVE_JAX = False
+
+def device_codec():
+    """:class:`~repro.kernels.ops.OnPairDevice`, or None where jax is not
+    installed or ``REPRO_NO_JAX`` opts out (numpy-only serving hosts). Any
+    other failure to import the kernels — a broken jax or libtpu install —
+    propagates: it must not turn into a quiet numpy store."""
+    if os.environ.get("REPRO_NO_JAX"):
+        return None
+    try:
+        from repro.kernels.ops import OnPairDevice
+    except ModuleNotFoundError as e:
+        if (e.name or "").split(".")[0] not in ("jax", "jaxlib"):
+            raise
+        return None
+    return OnPairDevice
+
 
 #: quantiles of the corpus token-count distribution that seed the bucket
 #: capacities (the last one is stretched to cover the true maximum).
@@ -115,26 +126,45 @@ class CompressedStringStore:
         self._locate_encoder: Encoder | None = None
         # hot/cold tiering (repro.store.tier); None until enable_tiering()
         self.tier = None
+        # the JAX device the tables live on (None: JAX's default); a
+        # sharded open spreads shards over the chips (_place)
+        self._placement = None
 
         # ----- backend resolution: per-codec registry capability, not an
         # isinstance/variant16 probe — an artifact opened on a jax-less host
         # resolves to numpy, a device-decodable codec routes to the kernels.
-        jax_ok = _HAVE_JAX and caps.device_decodable
+        if backend not in ("auto", "jax", "numpy"):
+            raise ValueError(f"unknown backend {backend!r}")
+        codec = (device_codec() if backend != "numpy"
+                 and caps.device_decodable else None)
         if backend == "auto":
-            backend = "jax" if jax_ok else "numpy"
-        elif backend == "jax" and not jax_ok:
+            backend = "jax" if codec is not None else "numpy"
+        elif backend == "jax" and codec is None:
             raise ValueError(
                 "jax backend unavailable: " +
-                (f"codec {compressor.name!r} is not device-decodable "
-                 "(registry capability)" if _HAVE_JAX else "jax not importable"))
-        elif backend not in ("jax", "numpy"):
-            raise ValueError(f"unknown backend {backend!r}")
+                ("jax not importable (or REPRO_NO_JAX set)"
+                 if caps.device_decodable else
+                 f"codec {compressor.name!r} is not device-decodable "
+                 "(registry capability)"))
         self.backend = backend
         # stats carries the resolved backend as a metric label, so it is
         # created only once backend resolution has run
         self.stats = StoreStats(backend=backend)
-        self._device = OnPairDevice(self.dictionary) if backend == "jax" else None
+        self._device = self._new_device(self.dictionary)
         self._set_bucket_caps(corpus.token_counts())
+
+    def _new_device(self, dictionary: PackedDictionary):
+        """Device tables for ``dictionary`` on this store's placement (None
+        on the numpy backend)."""
+        if self.backend != "jax":
+            return None
+        return device_codec()(dictionary, self._placement)
+
+    def _place(self, device) -> None:
+        """Keep this store's device tables on ``device`` from now on."""
+        self._placement = device
+        if self._device is not None:
+            self._device.place(device)
 
     def _set_bucket_caps(self, counts: np.ndarray) -> None:
         """Length buckets: static token capacities from corpus quantiles."""
@@ -599,6 +629,10 @@ class CompressedStringStore:
 
     def stats_snapshot(self) -> dict:
         snap = self.stats.snapshot(self.cache.stats())
+        if self._device is not None:
+            dev = self._device.device
+            snap["device"] = {"platform": dev.platform,
+                              "kind": dev.device_kind, "id": dev.id}
         snap.update(backend=self.backend, n_strings=self.n_strings,
                     n_sealed_strings=self.n_sealed,
                     n_tail_strings=self._tail_n(),

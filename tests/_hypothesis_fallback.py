@@ -1,7 +1,8 @@
 """Fallback stand-ins for hypothesis when it is not installed.
 
 Tier-1 must collect and run without optional dev deps (ROADMAP). Test modules
-do ``from _hypothesis_fallback import given, settings, st`` inside the
+do ``from _hypothesis_fallback import given, settings, st`` (and
+``example``, where they pin inputs) inside the
 ``except ImportError`` arm of their hypothesis import; property-based tests
 then collect as zero-argument functions that skip with a clear reason, while
 every non-property test in the module still runs.
@@ -29,6 +30,9 @@ def settings(*_args, **_kwargs):
         return fn
 
     return deco
+
+
+example = settings
 
 
 class _AnyStrategy:

@@ -6,8 +6,9 @@ full mutable lifecycle (extend -> seal -> save -> open -> multiget), the
 bounded compact-race retry, the non-token-stream refusal, client-side
 group-commit, and the jit-retrace bound on the device encode path.
 
-Importable without jax: device-path tests skip when OnPairDevice is None
-(REPRO_NO_JAX or no jax install), everything else runs on numpy alone.
+Importable without jax: device-path tests skip when the kernels are
+unavailable (REPRO_NO_JAX or no jax install), everything else runs on numpy
+alone.
 """
 
 import os
@@ -23,7 +24,8 @@ from repro.core.codec import Encoder
 from repro.core.lpm import parse_batch
 from repro.data.synth import load_dataset
 from repro.net import ShardServer
-from repro.store.mutable import MutableStringStore, OnPairDevice
+from repro.store.mutable import MutableStringStore
+from repro.store.store import device_codec
 
 SAMPLE = 1 << 18
 
@@ -31,6 +33,7 @@ SAMPLE = 1 << 18
 #: exactly one max-length entry, longer than any entry, every byte value
 EDGE = [b"", b"a", b"x" * 16, b"y" * 40, bytes(range(256))]
 
+OnPairDevice = device_codec()
 needs_jax = pytest.mark.skipif(OnPairDevice is None,
                                reason="jax unavailable (or REPRO_NO_JAX)")
 

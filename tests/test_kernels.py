@@ -1,6 +1,11 @@
-"""Per-kernel validation: Pallas (interpret) vs ref.py oracle vs Python
-reference, swept over shapes/dtypes/corpora, plus hypothesis property tests
-on the packing/compare primitives."""
+"""Per-kernel validation: Pallas (interpreted on the CPU) vs ref.py oracle vs
+Python reference, swept over shapes/dtypes/corpora, plus hypothesis property
+tests on the packing/compare primitives."""
+
+import json
+import os
+import subprocess
+import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +22,10 @@ from repro.core.packed import hash_key as np_hash_key, split_u64
 from repro.core.packing import pack_u64, shared_prefix_size
 from repro.data.synth import load_dataset
 from repro.kernels.ops import OnPairDevice
-from repro.kernels.ref import ctz32, hash_key, shared_prefix_bytes
+from repro.kernels.ref import hash_key, low_zero_bytes, shared_prefix_bytes
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src")
 
 
 @pytest.fixture(scope="module")
@@ -37,9 +45,9 @@ def device(trained):
 # ------------------------------------------------------------- primitives
 @given(st.integers(0, 2**32 - 1))
 @settings(max_examples=200, deadline=None)
-def test_ctz32_matches_python(x):
-    expected = 32 if x == 0 else (x & -x).bit_length() - 1
-    assert int(ctz32(jnp.uint32(x))) == expected
+def test_low_zero_bytes_matches_python(x):
+    expected = 4 if x == 0 else ((x & -x).bit_length() - 1) // 8
+    assert int(low_zero_bytes(jnp.uint32(x))) == expected
 
 
 @given(st.binary(min_size=0, max_size=8), st.binary(min_size=0, max_size=8))
@@ -141,3 +149,38 @@ def test_encode_shape_sweep(device, trained, length):
     assert enc == comp.compress_string(s)
     out = device.roundtrip([s], use_pallas=True)
     assert out == [s]
+
+
+# ------------------------------------------------------------ compile cache
+_CACHE_PROBE = """
+import json, os, sys
+import jax, jax.numpy as jnp
+from repro.kernels.cache import CACHE_DIR, use_compile_cache
+path = use_compile_cache()
+configured = jax.config.jax_compilation_cache_dir
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+print(json.dumps({"path": path, "configured": configured,
+                  "default": CACHE_DIR}))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_directory(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and receives the entries; without it
+    the cache is the checkout's fixed .jax_cache."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "REPRO_NO_JAX")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = SRC
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    want = str(tmp_path) if from_env else got["default"]
+    assert got["path"] == got["configured"] == want
+    assert os.path.basename(got["default"]) == ".jax_cache"
+    if from_env:
+        assert os.listdir(tmp_path), "no compiled entry was cached"

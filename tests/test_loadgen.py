@@ -5,6 +5,8 @@ from the manifest, and the Prometheus scrape round-trip the open-loop
 collector relies on."""
 
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -374,3 +376,25 @@ class TestScrapeRoundTrip:
         assert state == hist.state()
         other = hist_state_from_rows(rows, "rt_latency_us", {"shard": "1"})
         assert sum(other["counts"]) == 1
+
+
+def test_build_demo_corpus_leaves_jax_uninitialised(tmp_path):
+    """The spawner builds and saves without a device, so a shard server it
+    starts can own the chip."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = (
+        "import sys\n"
+        "from repro.loadgen.cluster import build_demo_corpus\n"
+        f"n = build_demo_corpus({str(tmp_path)!r}, n_shards=2, target_mib=1)\n"
+        "assert n > 0\n"
+        "if 'jax' in sys.modules:\n"
+        "    from jax._src import xla_bridge\n"
+        "    assert not xla_bridge.backends_are_initialized()\n"
+        "print('ok')\n")
+    env = {**os.environ, "PYTHONPATH": src}
+    env.pop("REPRO_NO_JAX", None)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"

@@ -4,6 +4,7 @@ compaction byte-identity, cache invalidation, service read/append
 interleaving, and sharded append/compact routing. Everything runs on a
 numpy-only host; the jax path is exercised implicitly when available."""
 
+import json
 import os
 import threading
 
@@ -595,3 +596,24 @@ def test_acceptance_full_lifecycle(titles, tmp_path):
     d = str(tmp_path / "acceptance")
     store.save(d)
     check(MutableStringStore.open(d))
+
+
+def test_persisted_pallas_encoder_raises_without_kernels(artifact, titles,
+                                                         tmp_path,
+                                                         monkeypatch):
+    """A saved encode_backend='pallas' that this host cannot honour raises
+    on open instead of quietly parsing on numpy."""
+    store = _mutable(artifact, titles[:300])
+    store.save(str(tmp_path))
+    meta_path = os.path.join(
+        CompressedStringStore._resolve_current(str(tmp_path)), "store.json")
+    with open(meta_path) as f:
+        meta = json.load(f)
+    meta["encode_backend"] = "pallas"
+    with open(meta_path, "w") as f:
+        json.dump(meta, f)
+    monkeypatch.setenv("REPRO_NO_JAX", "1")
+    with pytest.raises(ValueError, match="encode_backend='pallas'"):
+        MutableStringStore.open(str(tmp_path))
+    reopened = MutableStringStore.open(str(tmp_path), encode_backend="numpy")
+    assert reopened.multiget([0, 299]) == [titles[0], titles[299]]

@@ -372,6 +372,9 @@ def test_replica_failover_during_live_compact(titles, tmp_path):
         with pytest.raises(ValueError):  # a writable "replica" is refused
             dist.register_replica(1, servers[1].address)
         dist.register_replica(1, replica.address)
+        # the replica's first reads compile its decode shapes (jax backend);
+        # take that cost before the hand-off window timed below
+        dist.multiget(pre_ids, read_preference="replica")
 
         # stretch the compaction window so the hand-off is observable
         primary_store = servers[1].store

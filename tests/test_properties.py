@@ -18,11 +18,11 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:  # optional dev dep: property tests skip, the rest run
-    from _hypothesis_fallback import given, settings, st
+    from _hypothesis_fallback import example, given, settings, st
     HAVE_HYPOTHESIS = False
 
 try:
@@ -123,6 +123,7 @@ def test_encode_one_and_access(s):
 
 @pytest.mark.skipif(not HAVE_JAX, reason="jax unavailable")
 @given(strings=BATCH)
+@example(strings=[])  # an empty multiget once reached the kernel
 @settings(max_examples=10, deadline=None)
 def test_numpy_pallas_backend_equivalence(strings):
     for name in registry.names():

@@ -2,6 +2,7 @@
 get/multiget/scan against RawCompressor ground truth (OnPair + OnPair16),
 routing/bucketing invariants, cache accounting, and the micro-batch service."""
 
+import sys
 import threading
 
 import numpy as np
@@ -241,3 +242,44 @@ def test_stats_snapshot_shape(store16):
     # memory accounting includes the decode matrix + LPM tables
     assert store16.dictionary.resident_bytes > store16.dictionary.total_bytes
     assert snap["memory_bytes"] >= store16.dictionary.resident_bytes
+
+
+# ------------------------------------------------ no quiet numpy fallback
+class _KernelModule:
+    """Stands in for repro.kernels.ops: importing OnPairDevice raises."""
+
+    def __init__(self, exc):
+        self._exc = exc
+
+    def __getattr__(self, name):
+        raise self._exc
+
+
+def test_broken_kernel_import_fails_store_loudly(titles, monkeypatch):
+    """A kernel import that fails for any reason but a missing jax (here: a
+    libtpu that cannot start) must not resolve backend='auto' to numpy."""
+    comp = make_onpair16(sample_bytes=SAMPLE)
+    comp.train(titles[:2000])
+    corpus = comp.compress(titles[:2000])
+    monkeypatch.setitem(sys.modules, "repro.kernels.ops", _KernelModule(
+        RuntimeError("TPU backend failed to initialise")))
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        CompressedStringStore(comp, corpus)
+    # the numpy backend never asks for the kernels
+    assert CompressedStringStore(comp, corpus, backend="numpy").backend == \
+        "numpy"
+    # a host without jax is the one case that resolves to numpy
+    monkeypatch.setitem(sys.modules, "repro.kernels.ops", _KernelModule(
+        ModuleNotFoundError("No module named 'jax'", name="jax")))
+    assert CompressedStringStore(comp, corpus).backend == "numpy"
+
+
+def test_stats_name_backend_and_device(store16):
+    snap = store16.stats_snapshot()
+    if snap["backend"] == "jax":
+        import jax
+        dev = jax.devices()[0]
+        assert snap["device"] == {"platform": dev.platform,
+                                  "kind": dev.device_kind, "id": dev.id}
+    else:
+        assert "device" not in snap
