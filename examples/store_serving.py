@@ -72,7 +72,9 @@ with StoreService(store, max_batch=256, max_wait_s=0.002) as svc:
 
 snap = store.stats_snapshot()
 print(f"totals: {snap['lookups']} lookups, cache hit rate "
-      f"{snap['cache']['hit_rate']:.2f}, decode {snap['decode_mib_s']} MiB/s")
+      f"{snap['cache']['hit_rate']:.2f}, {snap['decoded_strings']} strings "
+      f"decoded ({snap['device_strings']} by the kernel), "
+      f"{snap['h2d_bytes']} B copied in, {snap['d2h_bytes']} B read back")
 
 # --- persistence + Client API v3: one session over the saved store ----------
 from repro.client import connect

@@ -61,6 +61,10 @@ from repro.store.mutable import MutableStringStore
 from repro.store.service import StoreService
 from repro.store.store import CompressedStringStore
 
+#: the root span of each client call
+_CLIENT_SPANS = {op: f"client.{op}" for op in (
+    "get", "multiget", "locate", "scan_prefix", "append", "extend")}
+
 
 class _GetBatcher:
     """Client-side coalescer for router point lookups.
@@ -84,15 +88,18 @@ class _GetBatcher:
         self._submit = submit  # client executor hand-off (trace-preserving)
         self.max_batch = int(max_batch)
         self._lock = threading.Lock()
-        self._pending: list[tuple] = []  # (id, pref, Future, TraceContext)
+        # (id, pref, Future, TraceContext, enqueue perf_counter_ns)
+        self._pending: list[tuple] = []
         self._in_flight = False
         self.batches = 0
         self.coalesced = 0  # gets answered in a client-side batch of > 1
 
     def submit_get(self, i: int, pref: str) -> Future:
         fut: Future = Future()
+        ctx = TRACER.current()
+        t0 = 0 if ctx is None else time.perf_counter_ns()
         with self._lock:
-            self._pending.append((int(i), pref, fut, TRACER.current()))
+            self._pending.append((int(i), pref, fut, ctx, t0))
             launch = not self._in_flight
             if launch:
                 self._in_flight = True
@@ -108,6 +115,12 @@ class _GetBatcher:
                 if not take:
                     self._in_flight = False
                     return
+            # one wait span per traced get: submit -> taken by this drain
+            taken = time.perf_counter_ns()
+            for _, _, _, ctx, t0 in take:
+                if ctx is not None:
+                    TRACER.record_child("client.coalesce", ctx, t0, taken,
+                                        batch=len(take))
             # cancelled-while-pending futures drop out before the wire;
             # survivors flip to RUNNING so a late cancel cannot race
             live = [item for item in take
@@ -127,17 +140,17 @@ class _GetBatcher:
         """One backend.multiget for every get in the group; the first traced
         caller's context parents the fused rpc spans (same convention as the
         service's coalesced decode)."""
-        ids = [i for i, _, _, _ in items]
-        ctx = next((c for _, _, _, c in items if c is not None), None)
+        ids = [item[0] for item in items]
+        ctx = next((item[3] for item in items if item[3] is not None), None)
         prev = TRACER.activate(ctx) if ctx is not None else None
         try:
             values = self._backend.multiget(ids, read_preference=pref)
         except Exception as exc:
-            for _, _, fut, _ in items:
-                fut.set_exception(exc)
+            for item in items:
+                item[2].set_exception(exc)
         else:
-            for (_, _, fut, _), v in zip(items, values):
-                fut.set_result(v)
+            for item, v in zip(items, values):
+                item[2].set_result(v)
         finally:
             if ctx is not None:
                 TRACER.restore(prev)
@@ -287,21 +300,26 @@ class StoreClient:
                  ctx=None, parent_id: int = 0) -> Future:
         """Attach session accounting (and the request's root span, when one
         was minted at submit time) to a backend/service future."""
+        t0_ns = int(t0 * 1e9)  # perf_counter and perf_counter_ns: one clock
+
         def _done(f: Future) -> None:
             nbytes = 0
             if not f.cancelled() and f.exception() is None:
                 nbytes = nbytes_of(f.result())
             if ctx is not None:
-                TRACER.record(f"client.{op}", ctx, parent_id, t0,
-                              time.perf_counter() - t0)
+                TRACER.record(_CLIENT_SPANS[op], ctx, parent_id, t0_ns,
+                              time.perf_counter_ns())
             self._record(op, t0, nbytes)
         fut.add_done_callback(_done)
         return fut
 
     def _trace_submit(self, submit):
-        """Mint this request's root span context, activate it around the
-        backend submit (queue items / executor jobs capture it there), and
-        return ``(future, ctx, parent_id)`` for :meth:`_tracked`."""
+        """With tracing on, mint this request's root span context, activate
+        it around the backend submit (queue items / executor jobs capture
+        it there), and return ``(future, ctx, parent_id)`` for
+        :meth:`_tracked`; with it off, ``(future, None, 0)``."""
+        if not TRACER.on:
+            return submit(), None, 0
         ctx, parent_id = TRACER.new_context()
         prev = TRACER.activate(ctx)
         try:
@@ -338,7 +356,7 @@ class StoreClient:
         remote transports stay bounded regardless by the socket timeout."""
         self._check_open()
         t0 = time.perf_counter()
-        with TRACER.span(f"client.{op}", root=True):
+        with TRACER.span(_CLIENT_SPANS[op], root=True):
             out = call()
         self._record(op, t0, nbytes_of(out))
         return out

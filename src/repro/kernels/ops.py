@@ -26,6 +26,18 @@ _DECODE_BATCHES = {
 }
 
 
+def _profiler_annotation(name: str):
+    """The tracer's hook in a process with JAX: while a profile is being
+    taken, each span is also a profiler annotation of the same name, so
+    the spans sit beside the device's operations in the trace."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name)
+    return None
+
+
+TRACER.annotate_with(_profiler_annotation)
+
+
 def _pad_to(x: int, multiple: int) -> int:
     return ((x + multiple - 1) // multiple) * multiple
 
@@ -96,6 +108,9 @@ class OnPairDevice:
         #: every (B, L) data shape handed to the encode kernels — tests assert
         #: this stays bounded under mixed-length workloads
         self.encode_shapes: set[tuple[int, int]] = set()
+        #: bytes of the arrays every decode batch copied in and read back
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
 
     @property
     def device(self):
@@ -211,25 +226,35 @@ class OnPairDevice:
 
     def decode_batch(self, tokens: np.ndarray, n_tokens: np.ndarray,
                      max_out: int, use_pallas: bool = True):
-        """Batched random-access decode: tokens int32[B,T] -> list[bytes]."""
+        """Batched random-access decode: tokens int32[B,T] -> list[bytes].
+
+        Each step is a span: ``kernel.h2d`` (the copies in), ``kernel.dispatch``
+        (the kernel call, which returns before the device is done),
+        ``kernel.wait``, ``kernel.d2h`` (the copies back) and
+        ``kernel.unpack`` (one ``bytes`` per row). ``h2d_bytes`` and
+        ``d2h_bytes`` count the bytes of every array copied each way.
+        """
         tokens = np.asarray(tokens, dtype=np.int32)
         n_tokens = np.asarray(n_tokens, dtype=np.int32)
         path = "pallas" if use_pallas else "ref"
         _DECODE_BATCHES[path].inc()
-        with TRACER.span("kernel.decode_batch", path=path,
-                         shape=list(tokens.shape)):
-            if use_pallas:
-                out, olen = onpair_decode.decode_compact(
-                    jnp.asarray(tokens), jnp.asarray(n_tokens),
-                    self.dd.mat16, self.dd.lens, max_out)
-            else:
-                out, olen = decode_batch_ref_jit(
-                    jnp.asarray(tokens), jnp.asarray(n_tokens),
-                    self.dd.mat16, self.dd.lens, max_out)
-        out = np.asarray(out)
-        olen = np.asarray(olen)
-        return [out[i, : olen[i]].astype(np.uint8).tobytes()
-                for i in range(out.shape[0])]
+        fn = (onpair_decode.decode_compact if use_pallas
+              else decode_batch_ref_jit)
+        with TRACER.span("kernel.h2d"):
+            tokens_d, n_tokens_d = jnp.asarray(tokens), jnp.asarray(n_tokens)
+        with TRACER.span("kernel.dispatch"):
+            out, olen = fn(tokens_d, n_tokens_d, self.dd.mat16, self.dd.lens,
+                           max_out)
+        with TRACER.span("kernel.wait"):
+            out.block_until_ready()
+            olen.block_until_ready()
+        with TRACER.span("kernel.d2h"):
+            out, olen = np.asarray(out), np.asarray(olen)
+        self.h2d_bytes += tokens.nbytes + n_tokens.nbytes
+        self.d2h_bytes += out.nbytes + olen.nbytes
+        with TRACER.span("kernel.unpack"):
+            return [out[i, : olen[i]].astype(np.uint8).tobytes()
+                    for i in range(out.shape[0])]
 
     def multiget_decode(self, token_lists: list[np.ndarray],
                         pad_tokens: int | None = None,
@@ -239,13 +264,18 @@ class OnPairDevice:
 
         Assembles the padded (B, T) matrix (see :func:`pack_token_matrix`)
         and runs the per-string decode kernel once; max_out = 16 * T is exact
-        for OnPair16 (every entry <= 16 B). Returns only the real rows.
+        for OnPair16 (every entry <= 16 B). Returns only the real rows. The
+        ``kernel.decode_batch`` span runs from packing (``kernel.pack``)
+        through the last row's bytes.
         """
         if not token_lists:
             return []
-        tokens, n_tokens = pack_token_matrix(token_lists, pad_tokens, pad_batch)
-        max_out = 16 * tokens.shape[1]
-        out = self.decode_batch(tokens, n_tokens, max_out, use_pallas=use_pallas)
+        with TRACER.span("kernel.decode_batch", batch=len(token_lists)):
+            with TRACER.span("kernel.pack"):
+                tokens, n_tokens = pack_token_matrix(token_lists, pad_tokens,
+                                                     pad_batch)
+            out = self.decode_batch(tokens, n_tokens, 16 * tokens.shape[1],
+                                    use_pallas=use_pallas)
         return out[: len(token_lists)]
 
     def roundtrip(self, strings: list[bytes], use_pallas: bool = True) -> list[bytes]:

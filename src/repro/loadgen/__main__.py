@@ -24,6 +24,7 @@ from repro.loadgen.cluster import LocalCluster, build_demo_corpus
 from repro.loadgen.driver import run_workload
 from repro.loadgen.slo import build_report, snapshot_server_states, write_report
 from repro.loadgen.spec import WorkloadSpec
+from repro.obs import TRACER
 
 
 def _parse_metrics_addrs(raw: str | None):
@@ -69,6 +70,9 @@ def main(argv=None) -> int:
 
     spec = (WorkloadSpec.from_file(args.spec) if args.spec
             else WorkloadSpec())
+    # requests carry trace ids, so the servers' slow-request logs (the SLO
+    # report's excerpts) hold whole traces; spawned servers trace as well
+    TRACER.enable()
 
     cluster = None
     try:

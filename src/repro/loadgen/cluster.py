@@ -9,7 +9,8 @@ replica addresses into the cluster manifest (so the client's replica
 autodiscovery wires read load-balancing on connect), and tears everything
 down on exit. Children run ``REPRO_NO_JAX=1``: a chip belongs to one
 process, so several shard processes on one host serve from numpy, and
-skipping the jax import keeps spawn latency off the measurement. The
+skipping the jax import keeps spawn latency off the measurement. They run
+``REPRO_TRACE=1`` too, so their slow-request logs fill. The
 spawner itself never initialises JAX (``build_demo_corpus`` builds with
 ``backend="numpy"``).
 """
@@ -31,7 +32,8 @@ _SRC = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def _child_env() -> dict:
-    env = {**os.environ, "REPRO_NO_JAX": "1"}
+    # tracing on, so an SLO report can carry the slow-request log's excerpt
+    env = {**os.environ, "REPRO_NO_JAX": "1", "REPRO_TRACE": "1"}
     env["PYTHONPATH"] = _SRC + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env

@@ -61,6 +61,8 @@ _IDEMPOTENT_OPS = frozenset(
     {P.OP_PING, P.OP_GET, P.OP_MULTIGET, P.OP_SCAN, P.OP_STATS,
      P.OP_TRACE_DUMP, P.OP_LOCATE, P.OP_SCAN_PREFIX, P.OP_TIER}
 )
+#: the span each request op opens around its socket exchange
+_RPC_SPANS = {op: f"rpc.{name}" for op, name in P.OP_NAMES.items()}
 
 
 class RemoteShardClient:
@@ -183,10 +185,9 @@ class RemoteShardClient:
                 self._probe_caps()
             except Exception:
                 pass  # unreachable/hostile: this call goes untraced on wire
-        with TRACER.span(f"rpc.{P.OP_NAMES.get(op, hex(op))}",
-                         shard=f"{self.address[0]}:{self.address[1]}") as ctx:
+        with TRACER.span(_RPC_SPANS.get(op, "rpc.unknown")):
             return self._exchange(op, payload, timeout,
-                                  ctx if self._traced else None)
+                                  TRACER.current() if self._traced else None)
 
     def _exchange(self, op: int, payload: bytes, timeout: float,
                   trace) -> bytes:

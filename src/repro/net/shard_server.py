@@ -50,6 +50,8 @@ from repro.store.service import StoreService
 from repro.store.store import CompressedStringStore
 
 _SHARD_DIR_RE = re.compile(r"^shard-(\d{4})$")
+#: the span each request op opens on the server
+_SERVER_SPANS = {op: f"server.{name}" for op, name in P.OP_NAMES.items()}
 
 
 def open_serving_store(
@@ -107,12 +109,12 @@ class _Handler(socketserver.BaseRequestHandler):
             if frame is None:
                 return  # clean EOF
             kind, payload, trace = frame
-            opname = P.OP_NAMES.get(kind, hex(kind))
             # a v2 frame's trace header joins this server's spans to the
-            # client's trace; v1 frames dispatch untraced (span() no-ops)
+            # client's trace; with none, the server's span starts a trace
             prev = TRACER.activate(trace) if trace is not None else None
             try:
-                with TRACER.span(f"server.{opname}"):
+                with TRACER.span(_SERVER_SPANS.get(kind, "server.unknown"),
+                                 root=True):
                     resp = shard.dispatch(kind, payload)
                 status = P.ST_OK
             except Exception as exc:

@@ -99,7 +99,7 @@ class StoreService:
             fut.set_exception(IndexError(
                 f"string id {i} out of range [0, {self.store.n_strings})"))
             return fut
-        self._enqueue(("get", i, fut, time.perf_counter(),
+        self._enqueue(("get", i, fut, time.perf_counter_ns(),
                        TRACER.current()), fut, 1)
         return fut
 
@@ -120,7 +120,7 @@ class StoreService:
                 fut.set_exception(IndexError(
                     f"string id {i} out of range [0, {n})"))
                 return fut
-        self._enqueue(("multiget", ids, fut, time.perf_counter(),
+        self._enqueue(("multiget", ids, fut, time.perf_counter_ns(),
                        TRACER.current()), fut, len(ids))
         return fut
 
@@ -136,7 +136,7 @@ class StoreService:
             fut.set_exception(TypeError(
                 "store is read-only (open a MutableStringStore to append)"))
             return fut
-        self._enqueue(("append", bytes(s), fut, time.perf_counter(),
+        self._enqueue(("append", bytes(s), fut, time.perf_counter_ns(),
                        TRACER.current()), fut, 1)
         return fut
 
@@ -153,7 +153,7 @@ class StoreService:
                 "store is read-only (open a MutableStringStore to append)"))
             return fut
         strings = [bytes(s) for s in strings]
-        self._enqueue(("extend", strings, fut, time.perf_counter(),
+        self._enqueue(("extend", strings, fut, time.perf_counter_ns(),
                        TRACER.current()), fut, len(strings))
         return fut
 
@@ -246,9 +246,16 @@ class StoreService:
                 return
             self.wakeups += 1
             raw = self._collect_batch(item)
+            start = time.perf_counter_ns()
             # cancelled futures drop out here; surviving ones flip to RUNNING
             # so a late cancel() cannot race set_result below
             batch = [b for b in raw if b[2].set_running_or_notify_cancel()]
+            # one queue-wait span per traced request: enqueue -> batch start,
+            # the price of micro-batching
+            for _, _, _, t0, ctx in batch:
+                if ctx is not None:
+                    TRACER.record_child("service.coalesce", ctx, t0, start,
+                                        batch=len(batch))
             # writes first: a client holding an id from a resolved append can
             # immediately read it back through the next batch
             writes = [b for b in batch if b[0] in ("append", "extend")]
@@ -257,16 +264,10 @@ class StoreService:
                 self._serve_writes(writes)
             if reads:
                 self._serve_reads(reads)
-            done = time.perf_counter()
-            lats = [done - t for _, _, _, t, _ in batch]
+            done = time.perf_counter_ns()
+            lats = [(done - t) * 1e-9 for _, _, _, t, _ in batch]
             for dt in lats:
                 self._lat.record(dt * 1e6)
-            # one coalesce-wait span per traced request: the enqueue→answer
-            # window a trace shows as the price of micro-batching
-            for _, _, _, t0, ctx in batch:
-                if ctx is not None:
-                    TRACER.record_child("service.coalesce", ctx, t0,
-                                        done - t0, batch=len(batch))
             if self.target_p99_s is not None:
                 self._adapt_wait(lats)
             if len(batch) > 1:
@@ -311,12 +312,18 @@ class StoreService:
             lo = len(strings)
             strings.extend([payload] if kind == "append" else payload)
             spans.append((lo, len(strings)))
+        ctx = next((c for _, _, _, _, c in writes if c is not None), None)
+        prev = TRACER.activate(ctx) if ctx is not None else None
         try:
-            new_ids = self.store.extend(strings)
+            with TRACER.span("service.batch", batch=len(strings)):
+                new_ids = self.store.extend(strings)
         except Exception as exc:
             for _, _, fut, _, _ in writes:
                 fut.set_exception(exc)
             return
+        finally:
+            if ctx is not None:
+                TRACER.restore(prev)
         self.appends += len(strings)
         self.append_batches += 1
         for (kind, _, fut, _, _), (lo, hi) in zip(writes, spans):
@@ -333,11 +340,12 @@ class StoreService:
             ids.extend([payload] if kind == "get" else payload)
             spans.append((lo, len(ids)))
         # the fused multiget serves every read in the batch, but a span needs
-        # ONE parent — attach store-side spans to the first traced request
+        # ONE parent — attach the batch's spans to the first traced request
         ctx = next((c for _, _, _, _, c in reads if c is not None), None)
         prev = TRACER.activate(ctx) if ctx is not None else None
         try:
-            values = self.store.multiget(ids)
+            with TRACER.span("service.batch", batch=len(ids)):
+                values = self.store.multiget(ids)
         except Exception as exc:  # fail the whole batch, keep serving
             for _, _, fut, _, _ in reads:
                 fut.set_exception(exc)

@@ -402,30 +402,30 @@ class CompressedStringStore:
         decode work happens).
         """
         t0 = time.perf_counter()
-        ids = [int(i) for i in ids]
-        n = self.n_strings
-        for i in ids:
-            if not 0 <= i < n:
-                raise IndexError(f"string id {i} out of range [0, {n})")
-        with self._lock:
-            if self.tier is not None:
-                self.tier.note_reads_locked(ids)
-            results: dict[int, bytes] = {}
-            misses: list[int] = []
-            for i in ids:  # unique-preserving cache probe: duplicates decode once
-                if i in results:
-                    continue
-                hit = self.cache.get(i)
-                if hit is not None:
-                    results[i] = hit
-                else:
-                    results[i] = b""  # claimed; overwritten by decode below
-                    misses.append(i)
-            if misses:
-                with TRACER.span("store.decode", batch=len(misses),
-                                 backend=self.backend):
-                    self._decode_misses(misses, results)
-            out = [results[i] for i in ids]
+        with TRACER.span("store.multiget"):
+            ids = [int(i) for i in ids]
+            n = self.n_strings
+            for i in ids:
+                if not 0 <= i < n:
+                    raise IndexError(f"string id {i} out of range [0, {n})")
+            with self._lock:
+                if self.tier is not None:
+                    self.tier.note_reads_locked(ids)
+                results: dict[int, bytes] = {}
+                misses: list[int] = []
+                for i in ids:  # unique-preserving probe: duplicates decode once
+                    if i in results:
+                        continue
+                    hit = self.cache.get(i)
+                    if hit is not None:
+                        results[i] = hit
+                    else:
+                        results[i] = b""  # claimed; overwritten by decode below
+                        misses.append(i)
+                if misses:
+                    with TRACER.span("store.decode", batch=len(misses)):
+                        self._decode_misses(misses, results)
+                out = [results[i] for i in ids]
         self.stats.record_multiget(len(ids), time.perf_counter() - t0)
         return out
 
@@ -659,20 +659,24 @@ class CompressedStringStore:
             hot, cold = self.tier.split_misses_locked(misses)
             if cold:
                 self.tier.decode_misses_locked(cold, results)
-                for pairs in cold.values():
-                    for gid, _ in pairs:
-                        self.cache.put(gid, results[gid])
+                self.stats.host_strings += sum(map(len, cold.values()))
+                with TRACER.span("store.cache_put"):
+                    for pairs in cold.values():
+                        for gid, _ in pairs:
+                            self.cache.put(gid, results[gid])
                 misses = hot
                 if not misses:
                     return
-        token_lists = [np.asarray(self._string_tokens(i), dtype=np.int32)
-                       for i in misses]
+        with TRACER.span("store.tokens", batch=len(misses)):
+            token_lists = [np.asarray(self._string_tokens(i), dtype=np.int32)
+                           for i in misses]
         if self._device is not None:
             self._decode_jax(misses, token_lists, results)
         else:
             self._decode_numpy(misses, token_lists, results)
-        for i in misses:
-            self.cache.put(i, results[i])
+        with TRACER.span("store.cache_put", batch=len(misses)):
+            for i in misses:
+                self.cache.put(i, results[i])
 
     def _decode_jax(self, misses: list[int], token_lists: list[np.ndarray],
                     results: dict[int, bytes]) -> None:
@@ -692,16 +696,22 @@ class CompressedStringStore:
             members = [k for k in range(len(misses)) if buckets[k] == b]
             for c0 in range(0, len(members), self.batch_size):
                 chunk = members[c0 : c0 + self.batch_size]
+                lists = [token_lists[k] for k in chunk]
+                device = self._device
+                h2d, d2h = device.h2d_bytes, device.d2h_bytes
                 t0 = time.perf_counter()
-                decoded = self._device.multiget_decode(
-                    [token_lists[k] for k in chunk], pad_tokens=cap,
-                    pad_batch=self.batch_size, use_pallas=self.use_pallas)
+                decoded = device.multiget_decode(
+                    lists, pad_tokens=cap, pad_batch=self.batch_size,
+                    use_pallas=self.use_pallas)
                 dt = time.perf_counter() - t0
                 for k, val in zip(chunk, decoded):
                     results[misses[k]] = val
                 self.stats.record_decode_batch(
                     (self.batch_size, cap), len(chunk),
-                    sum(len(v) for v in decoded), dt, jitted=True)
+                    sum(len(v) for v in decoded),
+                    sum(t.size for t in lists), dt, device=True,
+                    h2d_bytes=device.h2d_bytes - h2d,
+                    d2h_bytes=device.d2h_bytes - d2h)
 
     def _decode_numpy(self, misses: list[int], token_lists: list[np.ndarray],
                       results: dict[int, bytes]) -> None:
@@ -718,4 +728,4 @@ class CompressedStringStore:
             results[i] = val
         self.stats.record_decode_batch(
             (len(misses), int(counts.max()) if counts.size else 0),
-            len(misses), len(decoded), dt, jitted=False)
+            len(misses), len(decoded), tokens.size, dt, device=False)

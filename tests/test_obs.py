@@ -12,12 +12,18 @@ RPC.
 Everything here is stdlib + numpy, so the minimal-numpy CI job runs it.
 """
 
+import gc
+import glob
 import json
 import math
 import os
 import re
 import socket
+import subprocess
+import sys
 import threading
+import time
+import tracemalloc
 import urllib.error
 import urllib.request
 from bisect import bisect_left
@@ -60,6 +66,15 @@ def sharded_dir(titles, tmp_path_factory):
     d = str(tmp_path_factory.mktemp("obs") / "shards")
     save_sharded(store, d, 2)
     return d
+
+
+@pytest.fixture()
+def traced():
+    """Tracing on for one test (it is off by default), from an empty ring."""
+    TRACER.enable()
+    TRACER.clear()
+    yield TRACER
+    TRACER.disable()
 
 
 @pytest.fixture()
@@ -249,7 +264,8 @@ def test_prometheus_bucket_counts_sum_to_op_counter():
 
 # --------------------------------------------------------------------- tracer
 def test_span_is_noop_without_ambient_context():
-    tr = Tracer()
+    tr = Tracer(MetricsRegistry())
+    tr.enable()
     with tr.span("x") as ctx:
         assert ctx is None
         assert tr.current() is None
@@ -257,10 +273,12 @@ def test_span_is_noop_without_ambient_context():
 
 
 def test_nested_spans_chain_parentage():
-    tr = Tracer()
-    with tr.span("outer", root=True) as octx:
-        assert tr.current() == octx
-        with tr.span("inner", batch=3) as ictx:
+    tr = Tracer(MetricsRegistry())
+    tr.enable()
+    with tr.span("outer", root=True):
+        octx = tr.current()
+        with tr.span("inner", batch=3):
+            ictx = tr.current()
             assert ictx.trace_id == octx.trace_id
         assert tr.current() == octx  # inner restored the ambient context
     assert tr.current() is None
@@ -274,26 +292,202 @@ def test_nested_spans_chain_parentage():
 
 
 def test_record_child_books_queue_hops():
-    tr = Tracer()
+    tr = Tracer(MetricsRegistry())
+    tr.enable()
     root, _ = tr.new_context(None, inherit=False)
-    tr.record("root", root, 0, 0.0, 1.0)
-    child = tr.record_child("queue.wait", root, 0.1, 0.2, batch=7)
-    assert child.trace_id == root.trace_id
+    tr.record("root", root, 0, 0, 1_000_000_000)
+    tr.record_child("queue.wait", root, 100_000_000, 300_000_000, batch=7)
     (trace,) = tr.trace_dump()
+    assert trace["trace_id"] == root.trace_id
     (qspan,) = [s for s in trace["spans"] if s["name"] == "queue.wait"]
     assert qspan["parent_id"] == root.span_id
     assert qspan["annotations"] == {"batch": 7}
+    assert qspan["start_us"] == 100_000.0
+    assert qspan["duration_us"] == 200_000.0
 
 
 def test_trace_dump_slowest_first_and_ring_bounded():
-    tr = Tracer(max_spans=8)
+    tr = Tracer(MetricsRegistry())
+    tr.enable()
+    assert tr.capacity == 4096  # the slow-request log's default
+    tr.enable(capacity=8)
+    assert tr.capacity == 8
     for i in range(12):
         ctx, pid = tr.new_context(None, inherit=False)
-        tr.record(f"r{i}", ctx, pid, 0.0, (i + 1) / 1000.0)
+        tr.record(f"r{i}", ctx, pid, 0, (i + 1) * 1_000_000)
     dump = tr.trace_dump(4)
     assert [t["root"] for t in dump] == ["r11", "r10", "r9", "r8"]
     # the ring dropped the oldest spans: only 8 traces remain in total
     assert len(tr.trace_dump(100)) == 8
+    # the slow-request log's format
+    assert set(dump[0]) == {"trace_id", "duration_us", "root", "n_spans",
+                            "spans"}
+    (span,) = dump[0]["spans"]
+    assert span == {"name": "r11", "trace_id": dump[0]["trace_id"],
+                    "span_id": span["span_id"], "parent_id": 0,
+                    "start_us": 0.0, "duration_us": 12_000.0,
+                    "annotations": {}}
+    assert dump[0]["duration_us"] == 12_000.0 and dump[0]["n_spans"] == 1
+
+
+def test_off_tracer_allocates_nothing_and_mints_no_root():
+    tr = Tracer(MetricsRegistry())
+    ctx = TraceContext(new_trace_id(), 5)
+
+    def sites(n):
+        for _ in range(n):
+            with tr.span("store.decode", batch=3):
+                pass
+            with tr.span("client.get", root=True):
+                pass
+            tr.record_child("service.coalesce", ctx, 1, 2)
+            tr.current()
+
+    sites(100)  # first-call caches
+    gc.disable()
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        tracked = gc.get_count()[0]
+        sites(10_000)
+        tracked = gc.get_count()[0] - tracked
+        current, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+        gc.enable()
+    assert tracked == 0  # no object the collector tracks
+    assert current - base <= 0
+    assert peak - base < 1024  # the loop's own ints, nothing per span
+    assert tr.trace_dump() == [] and tr.current() is None
+
+
+def test_enabled_span_records_no_tracked_object():
+    tr = Tracer(MetricsRegistry())
+    tr.enable()
+
+    def nested(n):
+        for _ in range(n):
+            with tr.span("outer", root=True):
+                with tr.span("inner", batch=2):
+                    pass
+
+    nested(10)
+    gc.disable()
+    try:
+        tracked = gc.get_count()[0]
+        nested(2_000)
+        tracked = gc.get_count()[0] - tracked
+    finally:
+        gc.enable()
+    assert tracked == 0
+    assert len(tr.trace_dump(10_000)) == min(2_010, tr.capacity // 2)
+
+
+class _FakeClock:
+    """``perf_counter_ns`` that returns the times a test sets."""
+
+    def __init__(self):
+        self.now = 0
+
+    def perf_counter_ns(self):
+        return self.now
+
+
+def test_self_time_of_nested_spans_is_exact(monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    clock = _FakeClock()
+    monkeypatch.setattr(trace_mod, "time", clock)
+    reg = MetricsRegistry()
+    tr = Tracer(reg)
+    tr.enable()
+
+    def at(t, name=None, root=False):
+        clock.now = t
+        return tr.span(name, root=root) if name else None
+
+    # outer [0, 100): a [10, 30), b [40, 70) holding c [45, 50)
+    with at(0, "outer", root=True):
+        with at(10, "a"):
+            at(30)
+        with at(40, "b"):
+            with at(45, "c"):
+                at(50)
+            at(70)
+        at(100)
+    series = {(m["name"], m["labels"]["span"]): m["value"]
+              for m in reg.snapshot()["metrics"]}
+    own = {n: series[("repro_span_self_seconds_total", n)] * 1e9
+           for n in ("outer", "a", "b", "c")}
+    assert own == pytest.approx({"outer": 50, "a": 20, "b": 25, "c": 5})
+    assert series[("repro_span_seconds_total", "outer")] * 1e9 == \
+        pytest.approx(100)
+    assert series[("repro_span_total", "b")] == 1
+    # a second thread's spans add to the same counters
+    def other_thread():
+        with tr.span("c", root=True):
+            pass
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    counts = {m["labels"]["span"]: m["value"]
+              for m in reg.snapshot()["metrics"]
+              if m["name"] == "repro_span_total"}
+    assert counts == {"outer": 1, "a": 1, "b": 1, "c": 2}
+
+
+def test_enabled_spans_are_profiler_annotations_and_another_process_maps(
+        tmp_path):
+    """On a CPU profiler trace, every enabled span is an annotation of its
+    name, the buffered spans map onto them, and a span timed in another
+    process lands, by the same offset, inside the annotation that was open
+    across it, to within 1 ms."""
+    jax = pytest.importorskip("jax")
+    import repro.kernels.ops  # noqa: F401  (installs the annotation hook)
+    from repro.obs.trace import clock_offset
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    TRACER.enable()
+    TRACER.clear()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for k in range(6):
+            with TRACER.span("server.multiget", root=True):
+                with TRACER.span("store.decode"):
+                    time.sleep(0.001 * (k % 3 + 1))
+        with TRACER.span("server.wait", root=True):
+            out = subprocess.run(
+                [sys.executable, "-c",
+                 "import time; t0 = time.perf_counter_ns(); time.sleep(0.05);"
+                 " print(t0, time.perf_counter_ns())"],
+                capture_output=True, text=True, timeout=60).stdout
+    finally:
+        jax.profiler.stop_trace()
+        TRACER.disable()
+    c0, c1 = map(int, out.split())
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [(ev.name, int(ev.start_ns), int(ev.end_ns))
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name in ("server.multiget", "store.decode",
+                             "server.wait")]
+    names = [e[0] for e in events]
+    assert names.count("server.multiget") == 6
+    assert names.count("store.decode") == 6
+    spans = [(s[0], s[4]) for s in TRACER.spans()]
+    offset, residuals = clock_offset(
+        spans, [(name, start) for name, start, _ in events])
+    assert len(residuals) == 13
+    assert residuals[-1] < 1_000_000
+    (wait,) = [e for e in events if e[0] == "server.wait"]
+    assert wait[1] - 1_000_000 <= c0 + offset
+    assert c1 + offset <= wait[2] + 1_000_000
 
 
 # ------------------------------------------------------------------- protocol
@@ -349,7 +543,7 @@ def test_old_client_v1_frames_against_new_server(server):
         sock.close()
 
 
-def test_new_client_probes_and_falls_back_to_v1():
+def test_new_client_probes_and_falls_back_to_v1(traced):
     """Against a server that echoes the caps probe (= an old server), a
     traced client must keep every wire frame at v1 — no trace header."""
     listener = socket.create_server(("127.0.0.1", 0))
@@ -399,8 +593,33 @@ def test_caps_probe_against_new_server(server):
 
 
 # ----------------------------------------------------------------- end-to-end
-def test_trace_spans_local_shard_multiget(sharded_dir):
+def test_untraced_client_sends_no_trace_header(tcp_cluster, monkeypatch):
+    """Tracing off (the default): the client mints no root, never probes
+    for trace support, and no frame carries a trace header."""
+    sent = []
+    send = P.send_frame
+
+    def spy(sock, kind, payload=b"", trace=None):
+        sent.append(trace)
+        return send(sock, kind, payload, trace=trace)
+
+    monkeypatch.setattr(P, "send_frame", spy)
+    assert not TRACER.on
     TRACER.clear()
+    url = "tcp://" + ",".join(f"{h}:{p}" for h, p in
+                              (s.address for s in tcp_cluster))
+    client = connect(url)
+    try:
+        assert len(client.multiget([0, 1, 2, 3])) == 4
+        assert len(client.get_async(5).result(30)) > 0
+    finally:
+        client.close()
+    assert sent and all(t is None for t in sent)
+    assert all(c._traced is None for c in client.backend.clients)
+    assert TRACER.spans() == []
+
+
+def test_trace_spans_local_shard_multiget(sharded_dir, traced):
     client = connect(f"shard://{sharded_dir}")
     try:
         out = client.multiget([0, 1, 2, 5])
@@ -415,8 +634,7 @@ def test_trace_spans_local_shard_multiget(sharded_dir):
     _assert_parentage(trace)
 
 
-def test_tcp_multiget_trace_has_full_span_chain(tcp_cluster):
-    TRACER.clear()
+def test_tcp_multiget_trace_has_full_span_chain(tcp_cluster, traced):
     url = "tcp://" + ",".join(f"{h}:{p}" for h, p in (s.address for s in tcp_cluster))
     client = connect(url)
     try:
@@ -428,20 +646,19 @@ def test_tcp_multiget_trace_has_full_span_chain(tcp_cluster):
         t for t in TRACER.trace_dump(8) if t["root"] == "client.multiget"
     )
     names = {s["name"] for s in trace["spans"]}
-    # the acceptance chain: client -> socket -> server -> coalesce -> decode
+    # the chain: client -> socket -> server -> queue wait -> batch -> store
     assert {"client.multiget", "rpc.multiget", "server.multiget",
-            "service.coalesce", "store.decode"} <= names
-    assert trace["n_spans"] >= 4
+            "service.coalesce", "service.batch", "store.multiget",
+            "store.decode", "store.tokens", "store.cache_put"} <= names
+    assert trace["n_spans"] >= 9
     _assert_parentage(trace)
     decode = next(s for s in trace["spans"] if s["name"] == "store.decode")
-    assert decode["annotations"]["backend"]  # numpy | pallas | jax ...
     assert decode["annotations"]["batch"] >= 1
     coalesce = next(s for s in trace["spans"] if s["name"] == "service.coalesce")
     assert coalesce["annotations"]["batch"] >= 1
 
 
-def test_stats_metrics_extension_and_trace_dump_rpc(tcp_cluster):
-    TRACER.clear()
+def test_stats_metrics_extension_and_trace_dump_rpc(tcp_cluster, traced):
     client = RemoteShardClient(tcp_cluster[0].address)
     try:
         plain = client.stats()
@@ -457,6 +674,10 @@ def test_stats_metrics_extension_and_trace_dump_rpc(tcp_cluster):
         assert "repro_rpc_requests_total" in names
         assert "repro_store_multiget_latency_us" in names
         assert "repro_service_request_latency_us" in names
+        # the span counters ride the same extension
+        spans = {m["labels"]["span"] for m in stats["metrics"]["metrics"]
+                 if m["name"] == "repro_span_self_seconds_total"}
+        assert {"server.multiget", "service.batch", "store.multiget"} <= spans
         # the server's slow-request log is reachable over RPC and holds the
         # traced multiget with its server-side spans
         dump = client.trace_dump(16)
@@ -470,7 +691,8 @@ def test_stats_metrics_extension_and_trace_dump_rpc(tcp_cluster):
 # ----------------------------------------------------------------------- http
 def test_metrics_http_server_endpoints():
     reg = MetricsRegistry()
-    tr = Tracer()
+    tr = Tracer(reg)
+    tr.enable()
     h = reg.register(Histogram("repro_z_lat_us", labels={"backend": "numpy"}))
     c = reg.counter("repro_z_requests_total")
     for v in (2.0, 8.0, 40.0):
@@ -487,6 +709,7 @@ def test_metrics_http_server_endpoints():
         assert count and int(count.group(1)) == 3
         assert inf and int(inf.group(1)) == 3
         assert re.search(r"repro_z_requests_total 3\b", text)
+        assert re.search(r'repro_span_total\{span="req"\} 1\b', text)
         traces = json.loads(
             urllib.request.urlopen(base + "/traces?n=4").read().decode()
         )

@@ -283,3 +283,52 @@ def test_stats_name_backend_and_device(store16):
                                   "kind": dev.device_kind, "id": dev.id}
     else:
         assert "device" not in snap
+
+
+def test_copy_counters_count_what_each_decode_path_copies(titles, store16):
+    """The store counts the tokens that reach a decode, the strings each
+    path decodes and, on the device path, the bytes of every array a batch
+    copies in and reads back; the numpy path copies nothing."""
+    comp, corpus = store16.compressor, store16.corpus
+    ids = list(range(1000, 1700, 3))
+    tokens = sum(int(store16.segments.string_tokens(i).size) for i in ids)
+    np_store = CompressedStringStore(comp, corpus, backend="numpy",
+                                     cache_bytes=0)
+    assert np_store.multiget(ids) == [titles[i] for i in ids]
+    snap = np_store.stats_snapshot()
+    assert (snap["real_tokens"], snap["host_strings"], snap["device_strings"],
+            snap["h2d_bytes"], snap["d2h_bytes"]) == (tokens, len(ids), 0, 0, 0)
+    for gone in ("decode_seconds", "decode_mib_s", "lookups_per_s"):
+        assert gone not in snap
+    if store16.backend != "jax":
+        pytest.skip("jax backend unavailable")
+    import jax.numpy as jnp
+
+    from repro.kernels import onpair_decode
+    from repro.kernels.ops import pack_token_matrix
+
+    store = CompressedStringStore(comp, corpus, cache_bytes=0)
+    assert store.multiget(ids) == [titles[i] for i in ids]
+    snap = store.stats_snapshot()
+    assert (snap["real_tokens"], snap["device_strings"],
+            snap["host_strings"]) == (tokens, len(ids), 0)
+    # int32 tokens [B, T] and counts [B] go in, per batch of each shape
+    shapes = snap["batches_by_shape"]
+    assert sum(shapes.values()) == snap["batches"]
+    assert snap["h2d_bytes"] == sum(
+        n * (b * t * 4 + b * 4) for b, t in
+        (map(int, k.split("x")) for k in shapes) for n in [shapes[f"{b}x{t}"]])
+    dev = store._device
+    assert (snap["h2d_bytes"], snap["d2h_bytes"]) == (dev.h2d_bytes,
+                                                      dev.d2h_bytes)
+    # one batch by hand: the counts are the nbytes of the arrays copied
+    tok, n_tok = pack_token_matrix(
+        [store16.segments.string_tokens(i) for i in ids[:5]], pad_batch=8)
+    h0, d0 = dev.h2d_bytes, dev.d2h_bytes
+    dev.decode_batch(tok, n_tok, 16 * tok.shape[1])
+    out, olen = onpair_decode.decode_compact(
+        jnp.asarray(tok), jnp.asarray(n_tok), dev.dd.mat16, dev.dd.lens,
+        16 * tok.shape[1])
+    assert dev.h2d_bytes - h0 == tok.nbytes + n_tok.nbytes
+    assert dev.d2h_bytes - d0 == (np.asarray(out).nbytes
+                                  + np.asarray(olen).nbytes)
