@@ -9,6 +9,8 @@ compressed corpus's own footprint.
 
 from __future__ import annotations
 
+from collections import OrderedDict
+
 
 class LRUCache:
     """LRU over ``int id -> bytes`` with a decoded-bytes capacity budget.
@@ -19,7 +21,10 @@ class LRUCache:
 
     def __init__(self, capacity_bytes: int = 8 << 20):
         self.capacity_bytes = int(capacity_bytes)
-        self._data: dict[int, bytes] = {}  # dict preserves insertion = LRU order
+        # oldest first; an OrderedDict's links make eviction and the recency
+        # bump O(1), where a plain dict's next(iter()) walks the holes that
+        # earlier evictions left at its front
+        self._data: OrderedDict[int, bytes] = OrderedDict()
         self.current_bytes = 0
         self.hits = 0
         self.misses = 0
@@ -34,11 +39,11 @@ class LRUCache:
     _MISSING = object()  # sentinel: b"" is a valid cached value
 
     def get(self, key: int) -> bytes | None:
-        val = self._data.pop(key, self._MISSING)
+        val = self._data.get(key, self._MISSING)
         if val is self._MISSING:
             self.misses += 1
             return None
-        self._data[key] = val  # reinsert = move to most-recent position
+        self._data.move_to_end(key)
         self.hits += 1
         return val
 
@@ -49,14 +54,15 @@ class LRUCache:
             # never admit an entry the budget can't hold: it would evict the
             # whole cache and then pin current_bytes over capacity forever
             return
-        old = self._data.pop(key, None)
+        data = self._data
+        old = data.get(key)
         if old is not None:
             self.current_bytes -= len(old)
-        self._data[key] = value
+            data.move_to_end(key)
+        data[key] = value
         self.current_bytes += len(value)
-        while self.current_bytes > self.capacity_bytes and len(self._data) > 1:
-            old_key = next(iter(self._data))
-            self.current_bytes -= len(self._data.pop(old_key))
+        while self.current_bytes > self.capacity_bytes and len(data) > 1:
+            self.current_bytes -= len(data.popitem(last=False)[1])
             self.evictions += 1
 
     def clear(self) -> None:

@@ -183,6 +183,103 @@ def test_cache_stores_empty_strings():
     assert c.hits == 1 and c.misses == 0
 
 
+class _ReferenceLRU:
+    """The LRU rule spelled out on a list ordered oldest first."""
+
+    def __init__(self, capacity_bytes):
+        self.capacity_bytes = capacity_bytes
+        self.items: list[tuple[int, bytes]] = []
+        self.hits = self.misses = self.evictions = 0
+
+    @property
+    def current_bytes(self):
+        return sum(len(v) for _, v in self.items)
+
+    def _pop(self, key):
+        for n, (k, _) in enumerate(self.items):
+            if k == key:
+                return self.items.pop(n)
+        return None
+
+    def get(self, key):
+        item = self._pop(key)
+        if item is None:
+            self.misses += 1
+            return None
+        self.items.append(item)
+        self.hits += 1
+        return item[1]
+
+    def put(self, key, value):
+        if self.capacity_bytes <= 0 or len(value) > self.capacity_bytes:
+            return
+        self._pop(key)
+        self.items.append((key, value))
+        while self.current_bytes > self.capacity_bytes and len(self.items) > 1:
+            self.items.pop(0)
+            self.evictions += 1
+
+    def clear(self):
+        self.items.clear()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("capacity", [0, 3, 10, 64, 1000])
+def test_lru_cache_matches_reference_model(capacity, seed):
+    """Seeded get/put/clear sequences leave the cache and the list model in
+    the same state after every step: values, order, bytes and counters.
+    Value lengths run 0-16, so a budget of 3 refuses most values and b""
+    is common."""
+    rng = np.random.default_rng(seed)
+    c, ref = LRUCache(capacity_bytes=capacity), _ReferenceLRU(capacity)
+    for _ in range(3000):
+        op, key = rng.random(), int(rng.integers(0, 40))
+        if op < 0.45:
+            assert c.get(key) == ref.get(key)
+        elif op < 0.995:
+            n = 0 if rng.random() < 0.15 else int(rng.integers(1, 17))
+            value = bytes([key % 256]) * n
+            c.put(key, value)
+            ref.put(key, value)
+        else:
+            c.clear()
+            ref.clear()
+        assert list(c._data.items()) == ref.items
+        assert len(c) == len(ref.items)
+        assert c.current_bytes == ref.current_bytes <= max(capacity, 0)
+        assert (c.hits, c.misses, c.evictions) == \
+            (ref.hits, ref.misses, ref.evictions)
+    st = c.stats()
+    assert (st["entries"], st["bytes"], st["hits"], st["misses"],
+            st["evictions"]) == (len(ref.items), ref.current_bytes, ref.hits,
+                                 ref.misses, ref.evictions)
+
+
+def test_lru_cache_churn_evicts_oldest_first():
+    """250k puts of fresh keys through a 4 KiB budget, with every 101st
+    step a hit on the oldest entry: the budget holds throughout, and the
+    cache always holds the most recent keys in recency order."""
+    lens = np.random.default_rng(11).integers(0, 33, 250_000).tolist()
+    cap = 4096
+    c = LRUCache(capacity_bytes=cap)
+    order: list[int] = []  # live keys, oldest first
+    for key, n in enumerate(lens):
+        if key % 101 == 100:
+            oldest = order.pop(0)
+            assert c.get(oldest) == b"v" * lens[oldest]
+            order.append(oldest)
+        c.put(key, b"v" * n)
+        order.append(key)
+        del order[:-len(c)]
+        assert c.current_bytes <= cap
+        if key % 997 == 0:
+            assert list(c._data) == order
+    assert list(c._data) == order
+    assert c.current_bytes == sum(lens[k] for k in order)
+    assert c.evictions == len(lens) - len(c)
+    assert c.hits == len(lens) // 101 and c.misses == 0
+
+
 # ------------------------------------------------------------------- service
 def test_service_coalesces_and_matches(titles, store16):
     with StoreService(store16, max_batch=64, max_wait_s=0.002) as svc:
