@@ -4,15 +4,18 @@
 
 This process never imports JAX. It generates the cell's corpus from
 ``--seed``, builds and saves the store on the host with the configuration's
-settings, then starts the server child (``bench.serve``, the one process on
-the chip; started after the build so that its start-up does not share the
-host with the build), has it open and warm the store, fills the cache with
-the traffic's warm-up requests, and then drives the traffic through
-``repro.client.connect("tcp://...")`` for ``--seconds``. Every answer is
-kept and, once the window has closed, compared with the source strings
-(the plain reference). With ``--trace 1`` the child profiles exactly the
-window and the run reports the cell's per-layer metrics; otherwise its
-end-to-end metrics.
+settings (as ``shards`` segment-aligned shards sharing one dictionary where
+the configuration declares more than one), then starts the server child
+(``bench.serve``, the one process on the chips; started after the build so
+that its start-up does not share the host with the build), has it serve
+every shard on a port of its own and warm each store, fills the caches
+with the traffic's warm-up requests, and then drives the traffic through
+``repro.client.connect("tcp://<port 0>,...,<port N-1>")``, the program's
+router over the shards, for ``--seconds``. Every answer is kept and, once
+the window has closed, compared by global id with the source strings (the
+plain reference). With ``--trace 1`` the child profiles exactly the window
+and the run reports the cell's per-layer metrics; otherwise its end-to-end
+metrics.
 
 Progress goes to stdout line by line. The last stdout line is the result;
 the last stderr lines are the numbers compared, each with its limit. A run
@@ -40,6 +43,7 @@ import numpy as np
 _T0 = time.perf_counter()
 
 from bench import datasets, drive, hostwatch, layout, schedule  # noqa: E402
+from bench.fanout import FanoutClock  # noqa: E402
 from bench.latency import beyond, percentile  # noqa: E402
 from bench.roofline import peaks  # noqa: E402
 from bench.serve import FAULTS, REPLY  # noqa: E402
@@ -130,7 +134,12 @@ class Child:
 
 # ----------------------------------------------------------------- the data
 def build_store(config: dict, seed: int, path: str) -> dict:
-    """Generate the corpus and build, save the store (host only)."""
+    """Generate the corpus and build, save the store (host only). A
+    configuration of ``shards`` > 1 is built as one store, with one
+    dictionary trained once, and saved as that many segment-aligned
+    ``shard-000k`` directories beside the shared dictionary. ``paths``
+    lists the store directories to serve, in shard order."""
+    from repro.distributed.shard_store import save_sharded
     from repro.store import CompressedStringStore
 
     t0 = time.perf_counter()
@@ -141,32 +150,50 @@ def build_store(config: dict, seed: int, path: str) -> dict:
     store = CompressedStringStore.build(
         strings, codec=config["codec"], backend="numpy",
         sample_bytes=settings.pop("sample_bytes"), **settings)
-    store.save(path)
+    shards = int(config.get("shards", 1))
+    if shards == 1:
+        store.save(path)
+        paths = [path]
+    else:
+        bounds = save_sharded(store, path, shards)
+        paths = [os.path.join(path, f"shard-{k:04d}")
+                 for k in range(len(bounds))]
     t2 = time.perf_counter()
     return {"strings": strings, "raw_bytes": sum(map(len, strings)),
-            "generate_s": t1 - t0, "build_s": t2 - t1,
+            "generate_s": t1 - t0, "build_s": t2 - t1, "paths": paths,
             "compressed_bytes": int(store.corpus.compressed_bytes),
             "entries": int(store.dictionary.num_entries)}
 
 
 # ------------------------------------------------------------ the counters
 def counters(client) -> dict:
-    """The server's counters from the stats RPC, flattened."""
-    stats = client.backend.clients[0].stats()
-    store, ops = stats["store"], stats["ops"]
-    return {
-        "lookups": store["lookups"],
-        "decoded_strings": store["decoded_strings"],
-        "decoded_bytes": store["decoded_bytes"],
-        "batches": store["batches"],
-        "padded_rows": store["padded_rows"],
-        "jit_shapes": len(store["jit_shapes"]),
-        "cache_hits": store["cache"]["hits"],
-        "cache_misses": store["cache"]["misses"],
-        "rpc_multiget": ops.get("multiget", 0),
-        "rpc_get": ops.get("get", 0),
-        "memory_bytes": store["memory_bytes"],
-    }
+    """The counters of every server behind ``client``, from the stats RPC,
+    flattened and summed over the servers."""
+    out: dict = {}
+    for shard in client.backend.clients:
+        stats = shard.stats()
+        store, ops = stats["store"], stats["ops"]
+        for k, v in {
+            "lookups": store["lookups"],
+            "decoded_strings": store["decoded_strings"],
+            "decoded_bytes": store["decoded_bytes"],
+            "batches": store["batches"],
+            "padded_rows": store["padded_rows"],
+            "jit_shapes": len(store["jit_shapes"]),
+            "cache_hits": store["cache"]["hits"],
+            "cache_misses": store["cache"]["misses"],
+            "rpc_multiget": ops.get("multiget", 0),
+            "rpc_get": ops.get("get", 0),
+            "memory_bytes": store["memory_bytes"],
+        }.items():
+            out[k] = out.get(k, 0) + v
+    return out
+
+
+def shard_decoded(client) -> list[int]:
+    """Strings each server's store has decoded so far, in shard order."""
+    return [shard.stats()["store"]["decoded_strings"]
+            for shard in client.backend.clients]
 
 
 def delta(after: dict, before: dict) -> dict:
@@ -279,22 +306,33 @@ def serving(spec: dict, seed: int, platform: str | None, trace: bool = False,
             raise RunError(f"the cell needs {cell['chips']} chips, JAX "
                            f"reports {device['count']}")
         t_open = time.perf_counter()
-        opened = child.call("open", OPEN_TIMEOUT_S, path=store_dir,
+        opened = child.call("open", OPEN_TIMEOUT_S, paths=built["paths"],
                             trace=trace, fault=fault)
         log(f"server: ready {time.perf_counter() - t_open:.2f} s after the "
             f"build (open {opened['open_s']:.2f} s, bucket shapes "
-            f"{opened['compile_s']:.2f} s), bucket caps "
-            f"{opened['bucket_caps']}, first batch per shape "
-            f"{opened['first_batch_s']}, compile cache "
+            f"{opened['compile_s']:.2f} s), compile cache "
             f"{opened['compile_cache']} ({opened['cache_hits']} hits, "
-            f"{opened['cache_misses']} misses), store device "
-            f"{opened['store_device']}")
-        url = f"tcp://127.0.0.1:{opened['port']}"
+            f"{opened['cache_misses']} misses)")
+        for k, shard in enumerate(opened["shards"]):
+            log(f"shard {k}: port {shard['port']}, {shard['n_strings']} "
+                f"strings, bucket caps {shard['bucket_caps']}, first batch "
+                f"per shape {shard['first_batch_s']}, store device "
+                f"{shard['device']}")
+        url = "tcp://" + ",".join(f"127.0.0.1:{shard['port']}"
+                                  for shard in opened["shards"])
         clients = [connect(url) for _ in range(int(traffic.get("clients", 1)))]
+        declared = int(config.get("shards", 1))
+        served = len(clients[0].backend.clients)
+        if served != declared:
+            raise RunError(f"the configuration declares {declared} shards, "
+                           f"the run serves {served}")
+        if len(clients[0].backend) != len(strings):
+            raise RunError(f"the shards hold {len(clients[0].backend)} "
+                           f"strings, the corpus {len(strings)}")
         sampler = schedule.IdSampler(traffic["ids"], len(strings))
         # strings decoded from here on serve answers that are compared: the
         # server's own shape warm-up before it is left out
-        before_warm = counters(clients[0])
+        before_warm = shard_decoded(clients[0])
         t_warm = time.perf_counter()
         warm_cfg = traffic.get("warm", {})
         warm_wins = [warm(clients if len(clients) > 1 else clients * 4,
@@ -322,6 +360,8 @@ def run(args, root: str, platform: str | None, t0: float) -> dict:
                  args.fault) as s:
         child, clients = s["child"], s["clients"]
         before = counters(clients[0])
+        clock = (FanoutClock([c.backend for c in clients]) if args.trace
+                 else None)
         # the reference strings and set-up's objects are the benchmark's:
         # keep them out of the collections the client pays for in the window
         gc.freeze()
@@ -336,6 +376,10 @@ def run(args, root: str, platform: str | None, t0: float) -> dict:
         stopped = child.call("window_stop")
         gc.unfreeze()
         after = counters(clients[0])
+        # the fewest strings any one shard's kernel decoded for answers
+        # that are compared
+        kernel_strings = min(a - b for a, b in zip(
+            shard_decoded(clients[0]), s["before_warm"]))
     return {"spec": spec, "device": s["device"], "built": s["built"],
             "strings": s["strings"], "warm": s["warm"], "window": win,
             "setup_s": setup_s, "stopped": stopped,
@@ -344,8 +388,8 @@ def run(args, root: str, platform: str | None, t0: float) -> dict:
                      "server_gc": stopped.get("gc"),
                      "steal_s": hostwatch.steal_s(cpu0, cpu1)},
             "memory_bytes": after["memory_bytes"],
-            "kernel_strings": after["decoded_strings"]
-            - s["before_warm"]["decoded_strings"]}
+            "fanout": clock.summary() if clock else None,
+            "kernel_strings": kernel_strings}
 
 
 def quarters(win: drive.Window) -> list[int]:
@@ -427,7 +471,8 @@ def report(args, res: dict, root: str) -> tuple[dict, dict]:
         device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
         ctx = {"counters": {**counts, "real_tokens": stopped["real_tokens"]},
                "trace": trace, "peaks": peaks(dev["kind"], root),
-               "latency": {f"p{q}_ms": v for q, v in tails.items()}}
+               "latency": {f"p{q}_ms": v for q, v in tails.items()},
+               "fanout": res["fanout"]}
         for m in spec["per_layer"]:
             value = layout.metric_reader(m["name"], root)(ctx)
             if value is not None:

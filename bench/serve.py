@@ -4,19 +4,23 @@
 JSON lines: commands on stdin, replies on stdout after :data:`REPLY`.
 
 1. At start it reports the devices JAX sees, before any other work.
-2. ``open``: turns on the program's compile cache, opens the saved store
-   read-only through ``ShardServer.from_dir`` (the program's serving path),
-   serves it on a free port in this process, and compiles every decode
-   bucket shape of the store by one ``multiget`` that hits each bucket.
-   Under ``trace`` it also records host annotations around its calls into
-   each layer (``bench.trace.HOST_LAYERS``) and counts the real tokens that
-   reach the decode; a ``fault`` breaks the decode on purpose (the control
-   and the harness tests).
+2. ``open``: turns on the program's compile cache, opens each saved shard
+   directory read-only through ``ShardServer.from_dir`` (the program's
+   serving path; a ``shard-000k`` directory lands on its own device through
+   ``open_shard``), serves each on a free port in this process, checks that
+   every store decodes on the ``jax`` backend and that N shards sit on N
+   distinct devices, and compiles every decode bucket shape of each store
+   by one ``multiget`` that hits each bucket. Under ``trace`` it also
+   records host annotations around its calls into each layer
+   (``bench.trace.HOST_LAYERS``) and counts the real tokens that reach the
+   decode; a ``fault`` breaks every shard on purpose (the control and the
+   harness tests).
 3. ``window_start`` / ``window_stop``: bracket the measured window: count
    compile events in it, and under ``trace`` wrap exactly it in the
-   profiler. ``window_stop`` replies with the device's memory, the compile
-   count, the token count and the trace reduction.
-4. ``exit``: closes the server.
+   profiler. ``window_stop`` replies with the memory of the devices that
+   hold a shard, the compile count, the token count and the trace
+   reduction.
+4. ``exit``: closes the servers.
 """
 
 from __future__ import annotations
@@ -24,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
+import threading
 import time
 
 from bench.hostwatch import GcWatch
@@ -39,8 +44,11 @@ COMMANDS = ("open", "window_start", "window_stop")
 #: of every decode batch and leaves the rest empty; ``drop`` fails every
 #: 16th multiget, so its answer never comes; ``bypass`` answers every
 #: multiget from a table decoded on the host at open, so the answers are
-#: right but no string the run compares was decoded by the kernel
-FAULTS = ("lossy8", "flip", "half", "drop", "bypass")
+#: right but no string the run compares was decoded by the kernel;
+#: ``oneshard`` (two shards or more) answers every shard's local ids from
+#: the first shard's store, as if the fan-out to the other chips were left
+#: out
+FAULTS = ("lossy8", "flip", "half", "drop", "bypass", "oneshard")
 #: compile events counted inside the window
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -68,17 +76,20 @@ def _annotate(obj, attr: str, name: str) -> None:
     setattr(obj, attr, wrapped)
 
 
-def _count_tokens(device, counts: dict) -> None:
+def _count_tokens(device, counts: dict, lock) -> None:
     inner = device.multiget_decode
 
     def wrapped(token_lists, *args, **kw):
-        counts["real_tokens"] += sum(len(t) for t in token_lists)
+        n = sum(len(t) for t in token_lists)
+        with lock:   # every shard's batch thread adds to one count
+            counts["real_tokens"] += n
         return inner(token_lists, *args, **kw)
 
     device.multiget_decode = wrapped
 
 
-def _break(store, fault: str) -> None:
+def _break(store, fault: str, stores: list) -> None:
+    """Break ``store``, one of the served ``stores``, as ``fault`` says."""
     device = store._device
     if fault == "lossy8":
         dd = device.dd
@@ -115,6 +126,11 @@ def _break(store, fault: str) -> None:
     elif fault == "bypass":
         table = store.scan(0, store.n_strings)
         store.multiget = lambda ids: [table[int(i)] for i in ids]
+    elif fault == "oneshard":
+        if len(stores) < 2:
+            raise RuntimeError("fault oneshard needs two shards or more")
+        # ``stores[0]`` is broken first and keeps its own method
+        store.multiget = stores[0].multiget
     else:
         raise ValueError(f"unknown fault {fault!r}; known: {FAULTS}")
 
@@ -137,7 +153,8 @@ class Server:
         self.jax = jax
         self.compiles = 0
         self.counts = {"real_tokens": 0, **{e: 0 for e in CACHE_EVENTS}}
-        self.server = None
+        self.count_lock = threading.Lock()
+        self.servers: list = []
         self.trace_dir = None
         self.window_ann = None
         self.gc_watch = None
@@ -158,40 +175,55 @@ class Server:
         return {"platform": devs[0].platform, "kind": devs[0].device_kind,
                 "count": len(devs)}
 
-    def open(self, path: str, trace: bool, fault: str | None) -> dict:
+    def open(self, paths: list[str], trace: bool, fault: str | None
+             ) -> dict:
+        """Serve each store directory of ``paths`` (one per shard, in shard
+        order) from its own ``ShardServer`` in this process."""
         from repro.kernels.cache import use_compile_cache
         from repro.net.shard_server import ShardServer
 
         cache = use_compile_cache()
         t0 = time.perf_counter()
-        self.server = ShardServer.from_dir(path, read_only=True)
-        self.server.start()
-        store = self.server.store
-        if store.backend != "jax":
-            raise RuntimeError(f"store resolved backend {store.backend!r}, "
-                               "not jax")
-        if trace:
-            _count_tokens(store._device, self.counts)
-            _annotate(self.server, "dispatch", "bench.rpc")
-            _annotate(store, "multiget", "bench.store.multiget")
-            _annotate(store, "_decode_misses", "bench.store.decode_misses")
-            _annotate(store._device, "multiget_decode",
-                      "bench.kernel.multiget_decode")
-            _annotate(store._device, "decode_batch",
-                      "bench.kernel.decode_batch")
-        if fault:
-            _break(store, fault)
+        for path in paths:
+            self.servers.append(ShardServer.from_dir(path, read_only=True))
+            self.servers[-1].start()
+        stores = [srv.store for srv in self.servers]
+        backends = [store.backend for store in stores]
+        if any(b != "jax" for b in backends):
+            raise RuntimeError(f"stores resolved backends {backends}, not jax")
+        devices = [store._device.device for store in stores]
+        if len(set(devices)) != len(devices):
+            raise RuntimeError(f"{len(stores)} shards on devices {devices}: "
+                               "each shard needs a device of its own")
+        for srv, store in zip(self.servers, stores):
+            if trace:
+                _count_tokens(store._device, self.counts, self.count_lock)
+                _annotate(srv, "dispatch", "bench.rpc")
+                _annotate(store, "multiget", "bench.store.multiget")
+                _annotate(store, "_decode_misses",
+                          "bench.store.decode_misses")
+                _annotate(store._device, "multiget_decode",
+                          "bench.kernel.multiget_decode")
+                _annotate(store._device, "decode_batch",
+                          "bench.kernel.decode_batch")
+            if fault:
+                _break(store, fault, stores)
         t1 = time.perf_counter()
-        store.multiget(_warm_ids(store))
+        for store in stores:
+            store.multiget(_warm_ids(store))
         t2 = time.perf_counter()
-        snap = store.stats_snapshot()
-        return {"port": self.server.port, "open_s": t1 - t0,
-                "compile_s": t2 - t1, "compile_cache": cache,
+        shards = []
+        for srv, store in zip(self.servers, stores):
+            snap = store.stats_snapshot()
+            shards.append({"port": srv.port,
+                           "n_strings": int(store.n_strings),
+                           "bucket_caps": snap["bucket_caps"],
+                           "first_batch_s": snap["first_batch_s"],
+                           "device": snap.get("device")})
+        return {"shards": shards, "open_s": t1 - t0, "compile_s": t2 - t1,
+                "compile_cache": cache,
                 "cache_hits": self.counts[CACHE_EVENTS[0]],
-                "cache_misses": self.counts[CACHE_EVENTS[1]],
-                "bucket_caps": snap["bucket_caps"],
-                "first_batch_s": snap["first_batch_s"],
-                "store_device": snap.get("device")}
+                "cache_misses": self.counts[CACHE_EVENTS[1]]}
 
     def window_start(self, trace_dir: str | None) -> dict:
         self.trace_dir = trace_dir
@@ -209,6 +241,9 @@ class Server:
         return {}
 
     def window_stop(self) -> dict:
+        """The window's counts and trace, and the memory of the devices
+        that hold a shard: bytes in use summed over them, the peak of the
+        fullest."""
         self.window_ann.__exit__(None, None, None)
         self.gc_watch.__exit__()
         compiles = self.compiles - self.compiles0
@@ -219,16 +254,26 @@ class Server:
 
             self.jax.profiler.stop_trace()
             trace = reduce_trace(self.trace_dir)
-        dev = self.jax.devices()[0]
-        mem = dev.memory_stats() or {}
         return {"compiles": compiles, "real_tokens": tokens, "trace": trace,
                 "gc": self.gc_watch.summary(),
-                "bytes_in_use": mem.get("bytes_in_use"),
-                "peak_bytes_in_use": mem.get("peak_bytes_in_use")}
+                **device_memory(srv.store._device.device
+                                for srv in self.servers)}
 
     def close(self) -> None:
-        if self.server is not None:
-            self.server.close()
+        for srv in self.servers:
+            srv.close()
+
+
+def device_memory(devices) -> dict:
+    """``bytes_in_use`` summed over ``devices``, each counted once, and
+    ``peak_bytes_in_use`` of the fullest; None where a device reports
+    none (the CPU)."""
+    mems = [dev.memory_stats() or {} for dev in dict.fromkeys(devices)]
+    use = [m.get("bytes_in_use") for m in mems]
+    peak = [m.get("peak_bytes_in_use") for m in mems]
+    return {"bytes_in_use": None if None in use else sum(use),
+            "peak_bytes_in_use": None if None in peak or not peak
+            else max(peak)}
 
 
 def main() -> int:

@@ -245,6 +245,8 @@ def test_trace_busy_union_idle_share_and_gaps():
     assert out["window_s"] == 10.0
     assert out["busy_s"] == pytest.approx(2.0 + 1.0 + 1.0)
     assert out["compute_s"] == pytest.approx(2.0 + 1.0)   # no transfers
+    assert out["busy_s_per_device"] == [out["busy_s"]]
+    assert out["compute_s_per_device"] == [out["compute_s"]]
     gaps = dict(out["idle_gaps"])
     assert gaps == pytest.approx({"idle:kernel.decode_batch": 1.0,
                                   "idle:store.multiget": 0.0 + 1.0,
@@ -283,7 +285,9 @@ def test_roofline_counts_useful_bytes_and_counter_metrics():
                 "decoded_bytes": 9000, "cache_hits": 700, "cache_misses": 300,
                 "rpc_multiget": 40, "rpc_get": 0, "real_tokens": 2000}
     ctx = {"counters": counters, "peaks": {"hbm_bytes_per_s": peak},
-           "trace": {"compute_s": 1e-4, "busy_s": 2e-4, "window_s": 1.0},
+           "trace": {"compute_s": 1e-4, "busy_s": 2e-4, "window_s": 1.0,
+                     "compute_s_per_device": [1e-4],
+                     "busy_s_per_device": [2e-4]},
            "latency": {"p50_ms": 2.5, "p90_ms": 7.25, "p95_ms": 8.0,
                        "p99_ms": 9.0}}
     read = layout.metric_reader

@@ -323,8 +323,13 @@ def measure(args, root: str, platform: str | None) -> dict:
     try:
         with serving(spec, args.seed, platform) as s:
             child, clients = s["child"], s["clients"]
-            shard = clients[0].backend.clients[0]
+            # every shard's server runs in the one server process, so the
+            # first server's metrics and trace ring hold all their spans;
+            # the store counters are each server's own
+            shards = clients[0].backend.clients
+            shard = shards[0]
             srv0, cli0 = shard.stats(metrics=True), REGISTRY.snapshot()
+            store0 = [c.stats()["store"] for c in shards]
             TRACER.clear()  # the ring holds the window alone
             gc.freeze()
             child.call("window_start", trace_dir=trace_dir)
@@ -334,6 +339,7 @@ def measure(args, root: str, platform: str | None) -> dict:
             stopped = child.call("window_stop")
             gc.unfreeze()
             srv1, cli1 = shard.stats(metrics=True), REGISTRY.snapshot()
+            store1 = [c.stats()["store"] for c in shards]
             dump = shard.trace_dump(1 << 30)
             got = compare(win, s["strings"])
             device_kind = s["device"]["kind"]
@@ -350,8 +356,8 @@ def measure(args, root: str, platform: str | None) -> dict:
         span_delta(span_totals(srv1["metrics"]["metrics"]),
                    span_totals(srv0["metrics"]["metrics"])),
         span_delta(span_totals(cli1["metrics"]), span_totals(cli0["metrics"])))
-    store = {k: srv1["store"][k] - srv0["store"][k] for k in STORE_COUNTERS
-             if k in srv1["store"]}
+    store = {k: sum(b[k] - a[k] for a, b in zip(store0, store1))
+             for k in STORE_COUNTERS if k in store1[0]}
     done = win.t_done[:win.sent]
     lat_ms = list(win.latencies_s() * 1e3)
     return {

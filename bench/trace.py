@@ -6,18 +6,20 @@ layer it records host annotations named in :data:`HOST_LAYERS`. From the
 ``.xplane.pb`` this module takes:
 
 - ``window_s``: the length of the :data:`WINDOW` annotation;
-- ``busy_s``: the union of the intervals in which an operation ran on the
-  device (each device plane's op and async-op lines), clipped to the window
-  and averaged over the devices;
-- ``compute_s``: the same union over non-transfer operations only (the op
-  line, less any transfer), which a kernel's roofline share divides by. It
-  is not filtered by kernel name, so that a renamed or fused kernel still
-  counts;
+- ``busy_s_per_device``: for each device, the union of the intervals in
+  which an operation ran on it (its plane's op and async-op lines), clipped
+  to the window; ``busy_s`` is their mean over the devices;
+- ``compute_s_per_device``: the same union over non-transfer operations
+  only (the op line, less any transfer); ``compute_s`` is their mean. A
+  kernel's roofline share divides by their sum, the chip-seconds of
+  compute. Neither is filtered by kernel name, so that a renamed or fused
+  kernel still counts;
 - ``device_ops``: device seconds by operation (``opcode shape name``),
-  largest first;
-- ``idle_gaps``: the device's idle seconds in the window, by the innermost
+  summed over the devices, largest first;
+- ``idle_gaps``: each device's idle seconds in the window, by the innermost
   host layer that was running meanwhile (``idle:none`` when no annotated
-  layer was).
+  layer was), averaged over the devices, so that they add up to
+  ``window_s - busy_s``.
 """
 
 from __future__ import annotations
@@ -106,12 +108,17 @@ def reduce_events(device_lines, host_events, window):
 
     ``device_lines``: one list per device of ``(name, start, end, is_op)``
     events, ``is_op`` false for the async (transfer) line; ``host_events``:
-    ``(name, start, end)`` host annotations; ``window``: ``(start, end)``. Times in any one unit; seconds out when
-    given seconds in.
+    ``(name, start, end)`` host annotations; ``window``: ``(start, end)``.
+    Times in any one unit; seconds out when given seconds in. Each device's
+    idle time is charged to the host layers on its own, and the charges are
+    averaged over the devices; with no device, the whole window is idle.
     """
     win = [tuple(window)]
-    busy, compute, per_op = [], [], {}
-    for events in device_lines:
+    busy, compute, per_op, gaps = [], [], {}, {}
+    layers = [(layer, union((s, e) for n, s, e in host_events if n == layer))
+              for layer in HOST_LAYERS]
+    devices = device_lines or [[]]
+    for events in devices:
         ops = union((s, e) for _, s, e, _ in events)
         busy.append(total(intersect(ops, win)))
         comp = union((s, e) for n, s, e, is_op in events
@@ -122,26 +129,25 @@ def reduce_events(device_lines, host_events, window):
             if clipped > 0:
                 label = op_label(n)
                 per_op[label] = per_op.get(label, 0.0) + clipped
-    n_dev = max(len(device_lines), 1)
-    # idle gaps of the first device, charged to the innermost host layer
-    first = union((s, e) for _, s, e, _ in (device_lines[0] if device_lines
-                                            else []))
-    idle = subtract(win, first)
-    gaps = {}
-    for layer in HOST_LAYERS:
-        spans = union((s, e) for n, s, e in host_events if n == layer)
-        got = intersect(idle, spans)
-        if got:
-            gaps["idle:" + layer.removeprefix("bench.")] = total(got)
-            idle = subtract(idle, spans)
-    if total(idle) > 0:
-        gaps["idle:none"] = total(idle)
+        idle = subtract(win, ops)
+        for layer, spans in layers:
+            got = intersect(idle, spans)
+            if got:
+                key = "idle:" + layer.removeprefix("bench.")
+                gaps[key] = gaps.get(key, 0.0) + total(got)
+                idle = subtract(idle, spans)
+        if total(idle) > 0:
+            gaps["idle:none"] = gaps.get("idle:none", 0.0) + total(idle)
+    n_dev = len(devices)
     return {
         "window_s": float(window[1] - window[0]),
         "busy_s": sum(busy) / n_dev,
         "compute_s": sum(compute) / n_dev,
+        "busy_s_per_device": busy,
+        "compute_s_per_device": compute,
         "device_ops": sorted(per_op.items(), key=lambda kv: -kv[1])[:10],
-        "idle_gaps": sorted(gaps.items(), key=lambda kv: -kv[1])[:10],
+        "idle_gaps": sorted(((k, v / n_dev) for k, v in gaps.items()),
+                            key=lambda kv: -kv[1])[:10],
     }
 
 
