@@ -17,8 +17,9 @@ Backends:
   Works for every registered codec; the only backend when JAX is absent.
 * ``pallas`` — device path through :class:`repro.kernels.ops.OnPairDevice`
   (encode kernel + per-string decode kernel). Requires JAX and a codec whose
-  registry capabilities say ``device_decodable`` (onpair16's bounded-entry
-  token-stream layout).
+  registry capabilities say ``device_decodable`` (onpair, onpair16); the
+  encoder also needs ``bounded_entries`` (onpair16: the device LPM parse
+  assumes entries of at most 16 bytes).
 
 Both backends produce byte-identical results; tests pin that equivalence.
 """
@@ -34,8 +35,10 @@ from repro.core.artifact import DictArtifact
 BACKENDS = ("numpy", "pallas")
 
 
-def _check_backend(artifact: DictArtifact, backend: str):
-    """Resolve + validate; returns an OnPairDevice for the pallas backend."""
+def _check_backend(artifact: DictArtifact, backend: str, device=None,
+                   encode: bool = False):
+    """Resolve + validate; returns an OnPairDevice for the pallas backend
+    (``device`` when one is supplied)."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r} (one of {BACKENDS})")
     if backend == "numpy":
@@ -44,6 +47,13 @@ def _check_backend(artifact: DictArtifact, backend: str):
     if not caps.device_decodable:
         raise ValueError(f"codec {artifact.codec!r} is not device-decodable "
                          "(registry capability); use backend='numpy'")
+    if encode and not caps.bounded_entries:
+        raise ValueError(f"codec {artifact.codec!r} has entries over 16 "
+                         "bytes, which the device encoder does not parse "
+                         "(registry capability bounded_entries); use "
+                         "backend='numpy'")
+    if device is not None:
+        return device
     try:
         from repro.kernels.ops import OnPairDevice
     except Exception as e:  # jax missing on this host
@@ -66,10 +76,7 @@ class Encoder:
         # ``device`` optionally supplies an already-built OnPairDevice for the
         # same artifact (e.g. a store's decode device) so its packed tables
         # and compiled kernels are shared instead of rebuilt.
-        if device is not None and backend == "pallas":
-            self._device = device
-        else:
-            self._device = _check_backend(artifact, backend)
+        self._device = _check_backend(artifact, backend, device, encode=True)
         # the host codec (and its PackedDictionary rebuild) is only needed on
         # the numpy path; the pallas path decodes through the device tables
         self._codec = None

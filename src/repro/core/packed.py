@@ -132,6 +132,10 @@ class PackedDictionary:
     offsets: np.ndarray       # u32[n+1]
     lens: np.ndarray          # i32[n]
     mat16: np.ndarray         # u8[n, 16]  (first 16 bytes, zero padded)
+    # chained 16-byte rows (see chained_rows); None when every entry fits
+    # one row (variant16)
+    row_count: np.ndarray | None  # i32[n] rows per entry: ceil(len / 16)
+    row_next: np.ndarray | None   # i32[n] row of the first continuation
 
     # --- static LPM: short tier (<= 8 bytes) ---
     s_lo: np.ndarray
@@ -185,6 +189,11 @@ class PackedDictionary:
             head = e[:16]
             mat16[i, : len(head)] = np.frombuffer(head, dtype=np.uint8)
         variant16 = bool((lens <= 16).all())
+        row_count = row_next = None
+        if not variant16:
+            row_count = np.maximum(1, (lens + 15) // 16).astype(np.int32)
+            extra = row_count.astype(np.int64) - 1
+            row_next = (n + np.cumsum(extra) - extra).astype(np.int32)
 
         # short tier
         short_keys, short_payloads = [], []
@@ -241,6 +250,7 @@ class PackedDictionary:
         return cls(
             entries=entries, variant16=variant16,
             blob=blob, offsets=offsets, lens=lens, mat16=mat16,
+            row_count=row_count, row_next=row_next,
             s_lo=s_lo, s_hi=s_hi, s_len=s_len, s_tok=s_tok,
             s_probe_max=s_probe_max,
             p_lo=p_lo, p_hi=p_hi, p_len=p_len, p_bucket=p_bucket,
@@ -281,8 +291,54 @@ class PackedDictionary:
         arrays = (self.lens, self.mat16, self.s_lo, self.s_hi, self.s_len,
                   self.s_tok, self.p_lo, self.p_hi, self.p_len, self.p_bucket,
                   self.bucket_start, self.bucket_size, self.suf_lo,
-                  self.suf_hi, self.suf_len, self.suf_tok)
-        return self.total_bytes + sum(a.nbytes for a in arrays)
+                  self.suf_hi, self.suf_len, self.suf_tok, self.row_count,
+                  self.row_next)
+        return self.total_bytes + sum(a.nbytes for a in arrays
+                                      if a is not None)
+
+    @property
+    def num_rows(self) -> int:
+        """Rows of the chained decode matrix (:meth:`chained_rows`)."""
+        if self.row_count is None:
+            return self.num_entries
+        return int(self.row_count.sum())
+
+    def stream_rows(self, tokens: np.ndarray, bounds: np.ndarray) -> np.ndarray:
+        """Rows of the chained decode matrix that each token stream takes,
+        i64: stream ``i`` is ``tokens[bounds[i]:bounds[i + 1]]`` and takes
+        the sum of its tokens' ``row_count``, its token count where every
+        entry fits one row."""
+        bounds = np.asarray(bounds, dtype=np.int64)
+        if self.row_count is None:
+            return np.diff(bounds)
+        cum = np.zeros(len(tokens) + 1, dtype=np.int64)
+        np.cumsum(self.row_count[tokens], out=cum[1:])
+        return cum[bounds[1:]] - cum[bounds[:-1]]
+
+    def chained_rows(self) -> tuple[np.ndarray, np.ndarray]:
+        """The decode matrix as chained 16-byte rows, for decoders that copy
+        one fixed 16-byte row per step.
+
+        Returns ``(rows u8[n + x, 16], row_lens i32[n + x])``. Row ``t`` is
+        entry ``t``'s first 16 bytes (``mat16[t]``); an entry of ``L > 16``
+        bytes continues in ``ceil(L / 16) - 1`` rows from ``row_next[t]`` on,
+        appended after row ``n - 1``. ``row_lens`` is each row's byte count,
+        ``min(16, L - 16k)`` for the entry's ``k``-th row. A bounded
+        dictionary has ``x = 0``: the rows are ``mat16``, the counts ``lens``.
+        """
+        if self.row_count is None:
+            return self.mat16, self.lens
+        n = self.num_entries
+        extra = self.row_count.astype(np.int64) - 1
+        owner = np.repeat(np.arange(n), extra)
+        k = np.arange(owner.size) - np.repeat(self.row_next - n, extra) + 1
+        left = np.minimum(16, self.lens[owner] - 16 * k).astype(np.int32)
+        blob = np.concatenate([self.blob, np.zeros(16, dtype=np.uint8)])
+        cont = blob[self.offsets[owner].astype(np.int64)[:, None] + 16 * k[:, None]
+                    + _ARANGE16[None, :]]
+        cont[_ARANGE16[None, :] >= left[:, None]] = 0
+        return (np.concatenate([self.mat16, cont]),
+                np.concatenate([np.minimum(self.lens, 16), left]))
 
     # ----------------------------------------------------------------- decode
     def decode_tokens(self, tokens: np.ndarray) -> bytes:

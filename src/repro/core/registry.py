@@ -3,6 +3,7 @@ flags replacing scattered ``variant16`` / isinstance checks.
 
 The registry is the single answer to "what can codec X do?": the store asks
 ``device_decodable`` before routing multigets at the Pallas kernels, the
+device encoder asks ``bounded_entries``, the
 benchmark harness asks ``trainable`` before timing a training phase, and the
 persistence layer asks ``token_stream`` before slicing corpora on string
 boundaries. Capability flags are *static per codec* (they describe the
@@ -32,10 +33,10 @@ class CodecCaps:
     #: through PackedDictionary / the device kernels.
     token_stream: bool = False
     #: every dictionary entry is <= 16 bytes (the OnPair16 §3.2.2 bound that
-    #: enables the fixed-size-copy decode layout).
+    #: lets one 16-byte row hold an entry); the device LPM encode needs it.
     bounded_entries: bool = False
-    #: decodable by the Pallas/JAX kernels (requires token_stream + the
-    #: 16-byte-row layout).
+    #: decodable by the Pallas/JAX kernels (requires token_stream; an entry
+    #: over 16 bytes decodes as a chain of 16-byte rows).
     device_decodable: bool = False
     #: train() builds a real dictionary/table (vs a no-op for raw/block).
     trainable: bool = False
@@ -126,7 +127,8 @@ def _register_builtin() -> None:
         from_artifact=RawCompressor.from_artifact))
     register(CodecSpec(
         name="onpair",
-        caps=CodecCaps(token_stream=True, trainable=True),
+        caps=CodecCaps(token_stream=True, device_decodable=True,
+                       trainable=True),
         factory=make_onpair,
         from_artifact=OnPairCompressor.from_artifact))
     register(CodecSpec(

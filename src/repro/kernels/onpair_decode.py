@@ -40,6 +40,11 @@ from repro.kernels.platform import pallas_call
 LANES = 128
 #: strings per decode_compact grid step (one sublane tile)
 _ROWS = 8
+#: VMEM the packed dictionary may take: half of a v5e core's 128 MiB, the
+#: rest left to the output blocks. A table of exactly this size compiles for
+#: v5e at (256, 24) and (256, 64) row buckets; one of 128 MiB runs out of
+#: VMEM (tests/test_tpu_compile.py)
+DICT_VMEM_BYTES = 64 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -51,6 +56,12 @@ def pack_rows(mat16: jnp.ndarray) -> jnp.ndarray:
     n = mat16.shape[0]
     pad = _round_up(max(n, 1), 8) - n
     return jnp.pad(mat16, ((0, pad), (0, 0))).reshape(-1, LANES)
+
+
+def packed_bytes(rows: int) -> int:
+    """VMEM bytes of a ``(rows, 16)`` dictionary packed by :func:`pack_rows`
+    (int32 byte values, eight rows to a 128-lane row)."""
+    return _round_up(max(rows, 1), 8) * 16 * 4
 
 
 def _entry_row(dict_ref, tok):

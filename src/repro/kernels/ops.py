@@ -24,6 +24,10 @@ _DECODE_BATCHES = {
                                     labels={"path": path}))
     for path in ("pallas", "ref")
 }
+#: dictionary rows the device decodes gathered (one per token of a bounded
+#: dictionary), and the tokens among them whose entry is longer than 16 B
+_DICT_ROWS = REGISTRY.register(Counter("repro_kernel_dict_rows_total"))
+_LONG_TOKENS = REGISTRY.register(Counter("repro_kernel_long_tokens_total"))
 
 
 def _profiler_annotation(name: str):
@@ -88,17 +92,65 @@ def pack_token_matrix(token_lists: list[np.ndarray], pad_tokens: int | None = No
     return tokens, n_tokens
 
 
+def row_counts(tokens: np.ndarray, n_tokens: np.ndarray,
+               row_count: np.ndarray) -> np.ndarray:
+    """Dictionary rows of each token of a padded matrix: int32[B, T]
+    tokens, the first ``n_tokens`` of each row real -> int32[B, T], 0 on
+    padding."""
+    valid = np.arange(tokens.shape[1]) < n_tokens[:, None]
+    return np.where(valid, row_count[tokens], 0)
+
+
+def expand_rows(tokens: np.ndarray, counts: np.ndarray,
+                row_next: np.ndarray, width: int = 0
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """A padded token matrix -> its chained-row matrix, on the host.
+
+    Token ``t`` becomes row ``t`` and then its continuation rows from
+    ``row_next[t]`` on, ``counts`` rows in all (:func:`row_counts`;
+    :meth:`PackedDictionary.chained_rows`). Returns ``(rows int32[B, W],
+    n_rows int32[B])``, ``W`` the larger of ``width`` and the most rows of
+    a stream.
+    """
+    n_rows = counts.sum(axis=1, dtype=np.int64)
+    width = max(width, int(n_rows.max(initial=0)))
+    real = counts > 0
+    tok, cnt = tokens[real], counts[real]
+    total = int(cnt.sum())
+    k = np.arange(total) - np.repeat(np.cumsum(cnt) - cnt, cnt)
+    tok = np.repeat(tok, cnt)
+    rows = np.where(k == 0, tok, row_next[tok] + k - 1)
+    at = np.arange(total) - np.repeat(np.cumsum(n_rows) - n_rows, n_rows)
+    out = np.zeros((tokens.shape[0], width), dtype=np.int32)
+    out[np.repeat(np.arange(tokens.shape[0]), n_rows), at] = rows
+    return out, n_rows.astype(np.int32)
+
+
 class OnPairDevice:
-    """Device-side OnPair16 codec over a trained PackedDictionary."""
+    """Device-side OnPair codec over a trained PackedDictionary.
+
+    Decode takes any OnPair dictionary: an entry longer than 16 B is
+    decoded as a chain of 16-byte rows (:class:`DeviceDict`), the token
+    streams expanded into row streams on the host before the kernels run
+    (:func:`expand_rows`: numpy, cheaper a batch on a v5e than the same
+    expansion in jnp on the device). Encode (the device LPM parse) takes
+    only a bounded OnPair16 dictionary.
+    """
 
     def __init__(self, dictionary: PackedDictionary, device=None):
-        if not dictionary.variant16:
-            raise ValueError("device kernels target OnPair16 (<=16B entries); "
-                             "unbounded OnPair stays on the host path")
+        table = onpair_decode.packed_bytes(dictionary.num_rows)
+        if table > onpair_decode.DICT_VMEM_BYTES:
+            raise ValueError(
+                f"the dictionary's {dictionary.num_rows} 16-byte rows take "
+                f"{table} B of VMEM packed, over the decode kernels' budget "
+                f"of {onpair_decode.DICT_VMEM_BYTES} B")
         self.dictionary = dictionary
         # the tables live on ``device`` (JAX's default when None); every
         # kernel call runs where they are
         self.dd = DeviceDict.build(dictionary, device)
+        #: entries longer than 16 B: token streams are expanded into chained
+        #: rows before a kernel reads them (fixed when the dictionary is built)
+        self.chained = dictionary.row_count is not None
         # Bucketed-encode state: every launch uses a static
         # (encode_pad_batch, cap + 16) shape drawn from encode_len_caps, so
         # the number of compiled encode traces is bounded by the bucket set
@@ -129,7 +181,7 @@ class OnPairDevice:
         if not registry.capabilities(artifact.codec).device_decodable:
             raise ValueError(
                 f"codec {artifact.codec!r} is not device-decodable "
-                "(registry capability); only bounded-entry token-stream "
+                "(registry capability); only OnPair token-stream "
                 "dictionaries run on the kernels")
         return cls(PackedDictionary.build(artifact.entries))
 
@@ -144,6 +196,11 @@ class OnPairDevice:
         calls, unbounded retraces under a mixed workload. Serving paths go
         through :meth:`encode_bucketed`, which pins both.
         """
+        if self.chained:
+            raise ValueError(
+                "device encode needs a bounded dictionary (every entry <= "
+                "16 B, OnPair16); this one has longer entries: encode on "
+                "the host (backend='numpy')")
         data, lens = pack_strings(strings, pad_len=pad_len)
         if max_tokens is None:
             max_tokens = data.shape[1] - 16 or 1
@@ -205,10 +262,15 @@ class OnPairDevice:
                       tile: int = 1024) -> bytes:
         """Decode one token stream (any concatenation of compressed strings)."""
         tokens = np.asarray(tokens, dtype=np.int32)
-        n = tokens.size
-        max_out = int(self.dictionary.lens[tokens].sum()) if n else 0
-        if n == 0:
+        if tokens.size == 0:
             return b""
+        max_out = int(self.dictionary.lens[tokens].sum())
+        if self.chained:
+            tokens = self._expand(tokens[None, :],
+                                  np.array([tokens.size]))[0][0]
+        else:
+            _DICT_ROWS.inc(tokens.size)
+        n = tokens.size
         T = _pad_to(n, tile)
         padded = np.zeros(T, dtype=np.int32)
         padded[:n] = tokens
@@ -233,6 +295,10 @@ class OnPairDevice:
         ``kernel.wait``, ``kernel.d2h`` (the copies back) and
         ``kernel.unpack`` (one ``bytes`` per row). ``h2d_bytes`` and
         ``d2h_bytes`` count the bytes of every array copied each way.
+
+        With a chained dictionary, ``kernel.expand`` first turns the token
+        matrix into its row matrix, ``max(T, rows)`` wide, so the kernel
+        runs over rows.
         """
         tokens = np.asarray(tokens, dtype=np.int32)
         n_tokens = np.asarray(n_tokens, dtype=np.int32)
@@ -240,6 +306,10 @@ class OnPairDevice:
         _DECODE_BATCHES[path].inc()
         fn = (onpair_decode.decode_compact if use_pallas
               else decode_batch_ref_jit)
+        if self.chained:
+            tokens, n_tokens = self._expand(tokens, n_tokens, tokens.shape[1])
+        else:
+            _DICT_ROWS.inc(int(n_tokens.sum()))
         with TRACER.span("kernel.h2d"):
             tokens_d, n_tokens_d = jnp.asarray(tokens), jnp.asarray(n_tokens)
         with TRACER.span("kernel.dispatch"):
@@ -259,24 +329,52 @@ class OnPairDevice:
     def multiget_decode(self, token_lists: list[np.ndarray],
                         pad_tokens: int | None = None,
                         pad_batch: int | None = None,
-                        use_pallas: bool = True) -> list[bytes]:
+                        use_pallas: bool = True,
+                        rows: np.ndarray | None = None) -> list[bytes]:
         """Batched random-access decode of ragged token streams.
 
         Assembles the padded (B, T) matrix (see :func:`pack_token_matrix`)
-        and runs the per-string decode kernel once; max_out = 16 * T is exact
-        for OnPair16 (every entry <= 16 B). Returns only the real rows. The
-        ``kernel.decode_batch`` span runs from packing (``kernel.pack``)
+        and runs the per-string decode kernel once. T counts dictionary
+        rows: with a chained dictionary ``pad_tokens`` bounds each stream's
+        rows (a token of an entry over 16 B takes several), so max_out =
+        16 * T is exact as it is for OnPair16, where a token is a row.
+        ``rows`` gives each stream's rows where the caller has counted them
+        (:meth:`PackedDictionary.stream_rows`). Returns only the real rows.
+        The ``kernel.decode_batch`` span runs from packing (``kernel.pack``)
         through the last row's bytes.
         """
         if not token_lists:
             return []
         with TRACER.span("kernel.decode_batch", batch=len(token_lists)):
             with TRACER.span("kernel.pack"):
+                if self.chained:
+                    if rows is None:
+                        rows = self.dictionary.stream_rows(
+                            np.concatenate(token_lists),
+                            np.cumsum([0] + [len(t) for t in token_lists]))
+                    most = int(rows.max())
+                    if pad_tokens is not None and most > pad_tokens:
+                        raise ValueError(f"a stream has {most} rows > "
+                                         f"pad_tokens={pad_tokens}")
+                    pad_tokens = pad_tokens or max(most, 1)
                 tokens, n_tokens = pack_token_matrix(token_lists, pad_tokens,
                                                      pad_batch)
             out = self.decode_batch(tokens, n_tokens, 16 * tokens.shape[1],
                                     use_pallas=use_pallas)
         return out[: len(token_lists)]
+
+    def _expand(self, tokens: np.ndarray, n_tokens: np.ndarray,
+                width: int = 0) -> tuple[np.ndarray, np.ndarray]:
+        """A padded token matrix of a chained dictionary -> its row matrix
+        (:func:`expand_rows`), in the ``kernel.expand`` span. Counts the rows
+        and the tokens of entries over 16 B."""
+        with TRACER.span("kernel.expand"):
+            counts = row_counts(tokens, n_tokens, self.dictionary.row_count)
+            _LONG_TOKENS.inc(int(np.count_nonzero(counts > 1)))
+            rows, n_rows = expand_rows(tokens, counts,
+                                       self.dictionary.row_next, width)
+            _DICT_ROWS.inc(int(n_rows.sum()))
+        return rows, n_rows
 
     def roundtrip(self, strings: list[bytes], use_pallas: bool = True) -> list[bytes]:
         toks, n = self.encode_batch(strings, use_pallas=use_pallas)

@@ -53,11 +53,19 @@ def shared_prefix_bytes(lo1, hi1, lo2, hi2) -> jnp.ndarray:
 
 @dataclass(frozen=True)
 class DeviceDict:
-    """PackedDictionary uploaded as device arrays (static LPM + decode)."""
+    """PackedDictionary uploaded as device arrays (static LPM + decode).
+
+    The decode matrix holds the dictionary as chained 16-byte rows
+    (:meth:`PackedDictionary.chained_rows`): row ``t`` is entry ``t``'s
+    first 16 bytes, and an entry longer than 16 B continues in rows
+    appended after the ``N`` first ones (none for OnPair16). Where each
+    chain runs stays on the host (``PackedDictionary.row_count``,
+    ``row_next``), which expands token streams into row streams.
+    """
 
     # decode
-    mat16: jnp.ndarray       # int32[N, 16]   byte values
-    lens: jnp.ndarray        # int32[N]
+    mat16: jnp.ndarray       # int32[N + X, 16]   byte values
+    lens: jnp.ndarray        # int32[N + X]       bytes of each row
     # short tier
     s_lo: jnp.ndarray        # uint32[S]
     s_hi: jnp.ndarray
@@ -85,9 +93,10 @@ class DeviceDict:
         def put(x):
             return jax.device_put(np.asarray(x), device)
 
+        rows, row_lens = d.chained_rows()
         return DeviceDict(
-            mat16=put(d.mat16.astype(np.int32)),
-            lens=put(d.lens.astype(np.int32)),
+            mat16=put(rows.astype(np.int32)),
+            lens=put(row_lens.astype(np.int32)),
             s_lo=put(d.s_lo), s_hi=put(d.s_hi),
             s_len=put(d.s_len), s_tok=put(d.s_tok),
             p_lo=put(d.p_lo), p_hi=put(d.p_hi),

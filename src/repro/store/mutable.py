@@ -526,7 +526,7 @@ class MutableStringStore(CompressedStringStore):
         self.corpus = corpus
         self.segments = SegmentedCorpus.from_corpus(
             corpus, self.segments.strings_per_segment)
-        self._set_bucket_caps(corpus.token_counts())
+        self._set_bucket_caps(self._string_rows(corpus))
         self._device = (device if device is not None
                         else self._new_device(self.dictionary))
         self._encoder = (encoder if encoder is not None else

@@ -3,7 +3,8 @@
 Counts are exact and cumulative; a reader takes deltas between two
 snapshots. Where the decode copies, they count what it copies: the bytes
 of every array a device batch copied in and read back, the tokens that
-reached a decode, and the strings each path decoded.
+reached a decode and the dictionary rows the kernel gathered for them, and
+the strings each path decoded.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ class StoreStats:
         self.batches = 0            # kernel/numpy decode invocations
         self.padded_rows = 0        # batch rows incl. padding (waste metric)
         self.real_tokens = 0        # tokens of the strings that reached a decode
+        self.dict_rows = 0          # 16-byte dictionary rows the kernel gathered
         self.h2d_bytes = 0          # bytes of the arrays copied to the device
         self.d2h_bytes = 0          # bytes of the arrays read back
         self.device_strings = 0     # strings the kernel decoded
@@ -61,7 +63,9 @@ class StoreStats:
     def record_decode_batch(self, shape: tuple[int, int], n_real: int,
                             nbytes: int, n_tokens: int, seconds: float,
                             device: bool, h2d_bytes: int = 0,
-                            d2h_bytes: int = 0) -> None:
+                            d2h_bytes: int = 0, n_rows: int = 0) -> None:
+        """One decode batch; a device batch gathered ``n_rows`` dictionary
+        rows (one a token of OnPair16)."""
         self.batches += 1
         self.padded_rows += shape[0]
         self.decoded_strings += n_real
@@ -70,6 +74,7 @@ class StoreStats:
         self.batches_by_shape[shape] = self.batches_by_shape.get(shape, 0) + 1
         if device:
             self.device_strings += n_real
+            self.dict_rows += n_rows
             self.h2d_bytes += h2d_bytes
             self.d2h_bytes += d2h_bytes
             self.first_batch_s.setdefault(shape, seconds)
@@ -92,6 +97,7 @@ class StoreStats:
             "batches": self.batches,
             "padded_rows": self.padded_rows,
             "real_tokens": self.real_tokens,
+            "dict_rows": self.dict_rows,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
             "device_strings": self.device_strings,

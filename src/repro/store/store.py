@@ -9,14 +9,15 @@ Hot path (``multiget``): cache misses are routed through the segment layer
 to their token streams, *length-bucketed* into a small set of static padded
 ``(B, T)`` shapes, and decoded by the Pallas per-string kernel
 (``repro.kernels.onpair_decode.decode_compact`` via
-``OnPairDevice.multiget_decode``). Pinning both the batch dim and the token
-dim to at most ``num_buckets`` bucket capacities keeps the number of
-jit-compiled decode shapes bounded (<= num_buckets, default 4) no matter the
-query mix. The store serves from the vectorised numpy
+``OnPairDevice.multiget_decode``). T counts 16-byte dictionary rows: a
+token of OnPair16 is one row, a token of an unbounded OnPair entry over 16
+bytes a chain of several. Pinning both the batch dim and the row dim to at
+most ``num_buckets`` bucket capacities keeps the number of jit-compiled
+decode shapes bounded (<= num_buckets, default 4) no matter the query mix.
+The store serves from the vectorised numpy
 ``PackedDictionary.decode_tokens`` path when asked to (``backend="numpy"``),
-when the dictionary is unbounded OnPair (the 16-byte-row kernel cannot
-decode it), and under ``backend="auto"`` where jax is not installed or
-``REPRO_NO_JAX`` is set. ``stats_snapshot()`` names the backend it resolved
+and under ``backend="auto"`` where jax is not installed or ``REPRO_NO_JAX``
+is set. ``stats_snapshot()`` names the backend it resolved
 and, for ``jax``, the device.
 """
 
@@ -129,6 +130,8 @@ class CompressedStringStore:
         # the JAX device the tables live on (None: JAX's default); a
         # sharded open spreads shards over the chips (_place)
         self._placement = None
+        # the device tables warm_decode last compiled every bucket for
+        self._warmed = None
 
         # ----- backend resolution: per-codec registry capability, not an
         # isinstance/variant16 probe — an artifact opened on a jax-less host
@@ -151,7 +154,7 @@ class CompressedStringStore:
         # created only once backend resolution has run
         self.stats = StoreStats(backend=backend)
         self._device = self._new_device(self.dictionary)
-        self._set_bucket_caps(corpus.token_counts())
+        self._set_bucket_caps(self._string_rows(corpus))
 
     def _new_device(self, dictionary: PackedDictionary):
         """Device tables for ``dictionary`` on this store's placement (None
@@ -166,8 +169,15 @@ class CompressedStringStore:
         if self._device is not None:
             self._device.place(device)
 
+    def _string_rows(self, corpus: CompressedCorpus) -> np.ndarray:
+        """16-byte dictionary rows of each string of ``corpus``, i64: its
+        token count where every entry is one row (OnPair16)."""
+        return self.dictionary.stream_rows(
+            corpus.payload.view("<u2"),
+            np.asarray(corpus.offsets, dtype=np.int64) // 2)
+
     def _set_bucket_caps(self, counts: np.ndarray) -> None:
-        """Length buckets: static token capacities from corpus quantiles."""
+        """Length buckets: static row capacities from corpus quantiles."""
         if counts.size == 0:
             caps = [8]
         else:
@@ -680,7 +690,15 @@ class CompressedStringStore:
 
     def _decode_jax(self, misses: list[int], token_lists: list[np.ndarray],
                     results: dict[int, bytes]) -> None:
-        counts = np.asarray([t.size for t in token_lists], dtype=np.int64)
+        device = self._device
+        if device.chained and self._warmed is not device:
+            self.warm_decode()
+        # rows per string, counted once here for the buckets and the kernel
+        # (a bounded dictionary reads only the sizes: a token is a row)
+        sizes = [t.size for t in token_lists]
+        counts = self.dictionary.stream_rows(
+            np.concatenate(token_lists) if device.chained else (),
+            np.cumsum([0] + sizes))
         if counts.size and int(counts.max()) > int(self.bucket_caps[-1]):
             # appended strings can exceed every build-time bucket: grow a new
             # top bucket instead of indexing past the table. Growth is
@@ -697,12 +715,11 @@ class CompressedStringStore:
             for c0 in range(0, len(members), self.batch_size):
                 chunk = members[c0 : c0 + self.batch_size]
                 lists = [token_lists[k] for k in chunk]
-                device = self._device
                 h2d, d2h = device.h2d_bytes, device.d2h_bytes
                 t0 = time.perf_counter()
                 decoded = device.multiget_decode(
                     lists, pad_tokens=cap, pad_batch=self.batch_size,
-                    use_pallas=self.use_pallas)
+                    use_pallas=self.use_pallas, rows=counts[chunk])
                 dt = time.perf_counter() - t0
                 for k, val in zip(chunk, decoded):
                     results[misses[k]] = val
@@ -711,7 +728,24 @@ class CompressedStringStore:
                     sum(len(v) for v in decoded),
                     sum(t.size for t in lists), dt, device=True,
                     h2d_bytes=device.h2d_bytes - h2d,
-                    d2h_bytes=device.d2h_bytes - d2h)
+                    d2h_bytes=device.d2h_bytes - d2h,
+                    n_rows=int(counts[chunk].sum()))
+
+    def warm_decode(self) -> None:
+        """Compile the device decode at every bucket shape, by one empty
+        batch each; ``first_batch_s`` keeps each compile's seconds. A store
+        over a chained dictionary calls it before its first decode: its
+        buckets count rows, which a caller picking warm-up strings by their
+        token counts cannot aim at."""
+        self._warmed = self._device
+        for cap in self.bucket_caps:
+            shape = (self.batch_size, int(cap))
+            t0 = time.perf_counter()
+            self._device.multiget_decode(
+                [np.zeros(0, dtype=np.int32)], pad_tokens=shape[1],
+                pad_batch=shape[0], use_pallas=self.use_pallas)
+            self.stats.first_batch_s.setdefault(shape,
+                                                time.perf_counter() - t0)
 
     def _decode_numpy(self, misses: list[int], token_lists: list[np.ndarray],
                       results: dict[int, bytes]) -> None:

@@ -83,13 +83,19 @@ def test_capability_flags_match_behavior(titles, artifacts):
             assert caps.bounded_entries == all(
                 len(e) <= 16 for e in art.entries), name
 
-        # device_decodable implies the bounded token-stream layout; when jax
-        # is importable the device codec must actually construct
+        # device-decodable implies a token stream; device-encodable implies
+        # bounded entries. When jax is importable the device codec must
+        # actually construct, and the device encoder exactly where both hold
         if caps.device_decodable:
-            assert caps.token_stream and caps.bounded_entries, name
+            assert caps.token_stream, name
             jax = pytest.importorskip("jax")  # noqa: F841
             from repro.kernels.ops import OnPairDevice
             OnPairDevice.from_artifact(art)
+            if caps.bounded_entries:
+                Encoder(art, backend="pallas")
+            else:
+                with pytest.raises(ValueError, match="16"):
+                    Encoder(art, backend="pallas")
 
 
 # ----------------------------------------------------- artifact persistence

@@ -126,11 +126,18 @@ def test_numpy_backend_matches_jax_backend(titles, store16):
     assert np_store.multiget(ids) == store16.multiget(ids)
 
 
-def test_unbounded_onpair_rejects_jax_backend(store_unbounded):
-    if not store_unbounded.dictionary.variant16:
-        with pytest.raises(ValueError):
-            CompressedStringStore(store_unbounded.compressor,
-                                  store_unbounded.corpus, backend="jax")
+def test_unbounded_onpair_rejects_jax_backend(store_unbounded, monkeypatch):
+    """Unbounded OnPair decodes on the device (chained 16-byte rows): the
+    jax backend is refused only where the kernels are unavailable, never
+    for the codec."""
+    comp, corpus = store_unbounded.compressor, store_unbounded.corpus
+    assert not store_unbounded.dictionary.variant16
+    if store_unbounded.backend == "jax":
+        store = CompressedStringStore(comp, corpus, backend="jax")
+        assert store.backend == "jax" and store._device.chained
+    monkeypatch.setenv("REPRO_NO_JAX", "1")
+    with pytest.raises(ValueError, match="jax not importable"):
+        CompressedStringStore(comp, corpus, backend="jax")
 
 
 # ------------------------------------------------------------------ segments

@@ -104,3 +104,34 @@ def test_encode_batch_pallas_compiles_for_encode_bucket(one_chip, small_dict,
     _assert_kernel(onpair_encode.encode_batch_pallas.lower(
         _spec(one_chip, (64, cap + 16)), _spec(one_chip, (64,)), dd,
         max_tokens=cap))
+
+
+#: rows of a full-width chained (unbounded OnPair) dictionary: about 1.5
+#: rows an entry, as the URL column's dictionary has
+N_CHAINED_ROWS = 2 * N_ENTRIES
+#: the access_urls_onpair store's largest row bucket
+ROW_CAP = 24
+
+
+def test_decode_compact_compiles_for_chained_row_bucket(one_chip):
+    """The multiget kernel over row streams of an unbounded OnPair store at
+    its largest row bucket, with the continuation rows appended to the
+    dictionary matrix."""
+    _assert_kernel(onpair_decode.decode_compact.lower(
+        _spec(one_chip, (256, ROW_CAP)), _spec(one_chip, (256,)),
+        _spec(one_chip, (N_CHAINED_ROWS, 16)),
+        _spec(one_chip, (N_CHAINED_ROWS,)), max_out=16 * ROW_CAP))
+
+
+@pytest.mark.parametrize("T", [ROW_CAP, 64])
+def test_decode_compact_compiles_at_the_dictionary_vmem_budget(one_chip, T):
+    """The largest dictionary the device codec accepts (its packed table
+    exactly ``DICT_VMEM_BYTES``) compiles: a dictionary that passes the
+    check at open cannot fail to compile at its first decode."""
+    rows = onpair_decode.DICT_VMEM_BYTES // (16 * 4)
+    assert onpair_decode.packed_bytes(rows) == onpair_decode.DICT_VMEM_BYTES
+    assert onpair_decode.packed_bytes(rows + 1) > onpair_decode.DICT_VMEM_BYTES
+    _assert_kernel(onpair_decode.decode_compact.lower(
+        _spec(one_chip, (256, T)), _spec(one_chip, (256,)),
+        _spec(one_chip, (rows, 16)), _spec(one_chip, (rows,)),
+        max_out=16 * T))
