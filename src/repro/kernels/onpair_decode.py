@@ -183,10 +183,12 @@ def _compact_kernel(tok_ref, start_ref, n_ref, dict_ref, out_ref):
 @partial(jax.jit, static_argnames=("max_out",))
 def decode_compact(tokens: jnp.ndarray, n_tokens: jnp.ndarray,
                    mat16: jnp.ndarray, lens: jnp.ndarray, max_out: int):
-    """Per-string sequential decode: tokens int32[B,T] -> (out int32[B,max_out],
+    """Per-string sequential decode: tokens int32[B,T] -> (out uint8[B,max_out],
     out_len int32[B]). Grid = blocks of eight strings (each string decodes
     independently — the paper's random-access property is the parallelism
-    axis). Bytes past ``max_out`` are not written."""
+    axis). Bytes past ``max_out`` are not written. The kernel writes one
+    int32 word a byte; the cast to bytes fuses with the slice of its
+    output, so a copy back reads a quarter of the words."""
     B, T = tokens.shape
     valid = jnp.arange(T, dtype=jnp.int32)[None, :] < n_tokens[:, None]
     tl = jnp.where(valid, lens[tokens], 0)
@@ -218,4 +220,4 @@ def decode_compact(tokens: jnp.ndarray, n_tokens: jnp.ndarray,
         out_specs=pl.BlockSpec((_ROWS, 1, W), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1, W), jnp.int32),
     )(pad(tokens), pad(starts), pad(n_run[:, None]), pack_rows(mat16))
-    return out[:B, 0, :max_out], olen
+    return out[:B, 0, :max_out].astype(jnp.uint8), olen

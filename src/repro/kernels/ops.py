@@ -8,6 +8,9 @@ against repro.kernels.ref and the Python reference implementations.
 
 from __future__ import annotations
 
+import time
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +31,20 @@ _DECODE_BATCHES = {
 #: dictionary), and the tokens among them whose entry is longer than 16 B
 _DICT_ROWS = REGISTRY.register(Counter("repro_kernel_dict_rows_total"))
 _LONG_TOKENS = REGISTRY.register(Counter("repro_kernel_long_tokens_total"))
+#: device batches launched while an earlier batch of the same multiget was
+#: still uncollected: the batches whose copies and wait overlap another's
+_OVERLAPPED = REGISTRY.register(
+    Counter("repro_kernel_overlapped_batches_total"))
+
+
+class _Launched(NamedTuple):
+    """A batch on its way: the device's bytes (their copy back started),
+    the bytes of each string to cut from its first rows, and the bytes
+    copied in."""
+
+    out: jax.Array
+    lens: np.ndarray
+    h2d_bytes: int
 
 
 def _profiler_annotation(name: str):
@@ -288,20 +305,37 @@ class OnPairDevice:
 
     def decode_batch(self, tokens: np.ndarray, n_tokens: np.ndarray,
                      max_out: int, use_pallas: bool = True):
-        """Batched random-access decode: tokens int32[B,T] -> list[bytes].
-
-        Each step is a span: ``kernel.h2d`` (the copies in), ``kernel.dispatch``
-        (the kernel call, which returns before the device is done),
-        ``kernel.wait``, ``kernel.d2h`` (the copies back) and
-        ``kernel.unpack`` (one ``bytes`` per row). ``h2d_bytes`` and
-        ``d2h_bytes`` count the bytes of every array copied each way.
-
-        With a chained dictionary, ``kernel.expand`` first turns the token
-        matrix into its row matrix, ``max(T, rows)`` wide, so the kernel
-        runs over rows.
-        """
+        """Batched random-access decode: tokens int32[B,T] -> list[bytes],
+        one a row, each cut at ``max_out`` bytes. Launches the batch and
+        collects it at once (:meth:`_launch`, :meth:`_collect`)."""
         tokens = np.asarray(tokens, dtype=np.int32)
         n_tokens = np.asarray(n_tokens, dtype=np.int32)
+        return self._collect(self._launch(
+            tokens, n_tokens, self._string_lens(tokens, n_tokens, max_out),
+            max_out, use_pallas))
+
+    def _string_lens(self, tokens: np.ndarray, n_tokens: np.ndarray,
+                     max_out: int) -> np.ndarray:
+        """Decoded bytes of each row of a padded token matrix, at most
+        ``max_out``: the sum of its tokens' entry lengths. The host knows
+        them, so the device reads back bytes alone."""
+        valid = np.arange(tokens.shape[1]) < n_tokens[:, None]
+        lens = np.where(valid, self.dictionary.lens[tokens], 0).sum(axis=1)
+        return np.minimum(lens, max_out)
+
+    def _launch(self, tokens: np.ndarray, n_tokens: np.ndarray,
+                lens: np.ndarray, max_out: int,
+                use_pallas: bool) -> _Launched:
+        """Start one batch on the device and return without waiting.
+
+        Each step is a span: ``kernel.expand`` (a chained dictionary's token
+        matrix becomes its row matrix, ``max(T, rows)`` wide, so the kernel
+        runs over rows), ``kernel.h2d`` (the copies in) and
+        ``kernel.dispatch`` (the kernel call, which returns before the
+        device is done, and the start of the copy back of its bytes).
+        ``lens`` holds the bytes of the strings to cut from the first rows
+        (:meth:`_string_lens`).
+        """
         path = "pallas" if use_pallas else "ref"
         _DECODE_BATCHES[path].inc()
         fn = (onpair_decode.decode_compact if use_pallas
@@ -311,57 +345,107 @@ class OnPairDevice:
         else:
             _DICT_ROWS.inc(int(n_tokens.sum()))
         with TRACER.span("kernel.h2d"):
-            tokens_d, n_tokens_d = jnp.asarray(tokens), jnp.asarray(n_tokens)
+            tokens_d, n_tokens_d = jax.device_put((tokens, n_tokens),
+                                                  self.device)
         with TRACER.span("kernel.dispatch"):
-            out, olen = fn(tokens_d, n_tokens_d, self.dd.mat16, self.dd.lens,
-                           max_out)
+            out, _ = fn(tokens_d, n_tokens_d, self.dd.mat16, self.dd.lens,
+                        max_out)
+            out.copy_to_host_async()
+        h2d = tokens.nbytes + n_tokens.nbytes
+        self.h2d_bytes += h2d
+        return _Launched(out, lens, h2d)
+
+    def _collect(self, launched: _Launched) -> list[bytes]:
+        """A launched batch's strings: ``kernel.wait`` (the device),
+        ``kernel.d2h`` (the bytes read back, one copy) and
+        ``kernel.unpack`` (one ``bytes`` of the matrix, cut a string a
+        row)."""
         with TRACER.span("kernel.wait"):
-            out.block_until_ready()
-            olen.block_until_ready()
+            launched.out.block_until_ready()
         with TRACER.span("kernel.d2h"):
-            out, olen = np.asarray(out), np.asarray(olen)
-        self.h2d_bytes += tokens.nbytes + n_tokens.nbytes
-        self.d2h_bytes += out.nbytes + olen.nbytes
+            out = np.asarray(launched.out)
+        self.d2h_bytes += out.nbytes
         with TRACER.span("kernel.unpack"):
-            return [out[i, : olen[i]].astype(np.uint8).tobytes()
-                    for i in range(out.shape[0])]
+            buf, width = out.tobytes(), out.shape[1]
+            return [buf[i * width : i * width + n]
+                    for i, n in enumerate(launched.lens.tolist())]
 
     def multiget_decode(self, token_lists: list[np.ndarray],
-                        pad_tokens: int | None = None,
+                        pad_tokens: int | np.ndarray | None = None,
                         pad_batch: int | None = None,
                         use_pallas: bool = True,
-                        rows: np.ndarray | None = None) -> list[bytes]:
-        """Batched random-access decode of ragged token streams.
+                        rows: np.ndarray | None = None,
+                        on_batch=None) -> list[bytes]:
+        """Batched random-access decode of ragged token streams, in input
+        order.
 
-        Assembles the padded (B, T) matrix (see :func:`pack_token_matrix`)
-        and runs the per-string decode kernel once. T counts dictionary
-        rows: with a chained dictionary ``pad_tokens`` bounds each stream's
-        rows (a token of an entry over 16 B takes several), so max_out =
-        16 * T is exact as it is for OnPair16, where a token is a row.
-        ``rows`` gives each stream's rows where the caller has counted them
-        (:meth:`PackedDictionary.stream_rows`). Returns only the real rows.
-        The ``kernel.decode_batch`` span runs from packing (``kernel.pack``)
-        through the last row's bytes.
+        ``pad_tokens`` is a batch's row width, or the ascending widths of a
+        caller's length buckets: each stream goes to the smallest that
+        holds its rows, and a bucket's streams are cut into batches of
+        ``pad_batch`` (:func:`pack_token_matrix`), so the kernel runs at a
+        small, static set of shapes. With neither, one batch shaped by the
+        lists. T counts dictionary rows: with a chained dictionary a token
+        of an entry over 16 B takes several, so max_out = 16 * T is exact
+        as it is for OnPair16, where a token is a row. ``rows`` gives each
+        stream's rows where the caller has counted them
+        (:meth:`PackedDictionary.stream_rows`).
+
+        Every batch is launched before the first is collected, so the host
+        packs and copies in the next batch while the device decodes the
+        last and copies it back. A ``kernel.decode_batch`` span runs from
+        a batch's packing (``kernel.pack``) to its dispatch; the collects
+        follow. ``on_batch(shape, members, decoded, seconds, h2d_bytes,
+        d2h_bytes)`` hears of each batch once collected: the indices of its
+        streams, their strings, the host seconds spent on it and the bytes
+        copied each way.
         """
-        if not token_lists:
+        n = len(token_lists)
+        if not n:
             return []
-        with TRACER.span("kernel.decode_batch", batch=len(token_lists)):
-            with TRACER.span("kernel.pack"):
-                if self.chained:
-                    if rows is None:
-                        rows = self.dictionary.stream_rows(
-                            np.concatenate(token_lists),
-                            np.cumsum([0] + [len(t) for t in token_lists]))
-                    most = int(rows.max())
-                    if pad_tokens is not None and most > pad_tokens:
-                        raise ValueError(f"a stream has {most} rows > "
-                                         f"pad_tokens={pad_tokens}")
-                    pad_tokens = pad_tokens or max(most, 1)
-                tokens, n_tokens = pack_token_matrix(token_lists, pad_tokens,
-                                                     pad_batch)
-            out = self.decode_batch(tokens, n_tokens, 16 * tokens.shape[1],
-                                    use_pallas=use_pallas)
-        return out[: len(token_lists)]
+        if rows is None:
+            rows = self.dictionary.stream_rows(
+                np.concatenate(token_lists) if self.chained else (),
+                np.cumsum([0] + [len(t) for t in token_lists]))
+        rows = np.asarray(rows)[:n]  # a wrapper may pass fewer lists on
+        most = int(rows.max())
+        caps = np.atleast_1d(pad_tokens if pad_tokens is not None
+                             else max(most, 1))
+        if most > int(caps[-1]):
+            raise ValueError(f"a stream has {most} rows > "
+                             f"pad_tokens={int(caps[-1])}")
+        buckets = np.searchsorted(caps, rows, side="left")
+        launched = []
+        for b in np.unique(buckets):
+            members = np.flatnonzero(buckets == b)
+            size = pad_batch or members.size
+            for c0 in range(0, members.size, size):
+                chunk = members[c0 : c0 + size]
+                t0 = time.perf_counter()
+                with TRACER.span("kernel.decode_batch", batch=chunk.size):
+                    with TRACER.span("kernel.pack"):
+                        tokens, n_tokens = pack_token_matrix(
+                            [token_lists[k] for k in chunk], int(caps[b]),
+                            pad_batch)
+                        lens = self._string_lens(tokens[: chunk.size],
+                                                 n_tokens[: chunk.size],
+                                                 16 * tokens.shape[1])
+                    if launched:
+                        _OVERLAPPED.inc()
+                    batch = self._launch(tokens, n_tokens, lens,
+                                         16 * tokens.shape[1], use_pallas)
+                launched.append((chunk, batch, tokens.shape,
+                                 time.perf_counter() - t0))
+        out: list[bytes] = [b""] * n
+        for chunk, batch, shape, seconds in launched:
+            t0 = time.perf_counter()
+            decoded = self._collect(batch)
+            for k, v in zip(chunk.tolist(), decoded):
+                out[k] = v
+            if on_batch is not None:
+                on_batch(shape, chunk, decoded,
+                         seconds + time.perf_counter() - t0,
+                         batch.h2d_bytes, batch.out.nbytes)
+        return out
 
     def _expand(self, tokens: np.ndarray, n_tokens: np.ndarray,
                 width: int = 0) -> tuple[np.ndarray, np.ndarray]:

@@ -309,7 +309,10 @@ def encode_batch_ref(data: jnp.ndarray, str_lens: jnp.ndarray,
 # ============================================================ jit wrappers
 @partial(jax.jit, static_argnames=("max_out",))
 def decode_batch_ref_jit(tokens, n_tokens, mat16, lens, max_out: int):
-    return decode_batch_ref(tokens, n_tokens, mat16, lens, max_out)
+    """decode_batch_ref with its bytes as uint8, as ``decode_compact``
+    returns them."""
+    out, out_len = decode_batch_ref(tokens, n_tokens, mat16, lens, max_out)
+    return out.astype(jnp.uint8), out_len
 
 
 @partial(jax.jit, static_argnames=("max_tokens",))

@@ -708,28 +708,22 @@ class CompressedStringStore:
             self.bucket_caps = np.append(
                 self.bucket_caps,
                 max(_ceil8(int(counts.max())), 2 * int(self.bucket_caps[-1])))
-        buckets = np.searchsorted(self.bucket_caps, counts, side="left")
-        for b in np.unique(buckets):
-            cap = int(self.bucket_caps[int(b)])
-            members = [k for k in range(len(misses)) if buckets[k] == b]
-            for c0 in range(0, len(members), self.batch_size):
-                chunk = members[c0 : c0 + self.batch_size]
-                lists = [token_lists[k] for k in chunk]
-                h2d, d2h = device.h2d_bytes, device.d2h_bytes
-                t0 = time.perf_counter()
-                decoded = device.multiget_decode(
-                    lists, pad_tokens=cap, pad_batch=self.batch_size,
-                    use_pallas=self.use_pallas, rows=counts[chunk])
-                dt = time.perf_counter() - t0
-                for k, val in zip(chunk, decoded):
-                    results[misses[k]] = val
-                self.stats.record_decode_batch(
-                    (self.batch_size, cap), len(chunk),
-                    sum(len(v) for v in decoded),
-                    sum(t.size for t in lists), dt, device=True,
-                    h2d_bytes=device.h2d_bytes - h2d,
-                    d2h_bytes=device.d2h_bytes - d2h,
-                    n_rows=int(counts[chunk].sum()))
+
+        def record(shape, members, decoded, seconds, h2d_bytes, d2h_bytes):
+            self.stats.record_decode_batch(
+                shape, members.size, sum(map(len, decoded)),
+                sum(token_lists[k].size for k in members), seconds,
+                device=True, h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes,
+                n_rows=int(counts[members].sum()))
+
+        # one call for every miss: the device cuts them into the buckets'
+        # batches and launches them all before it reads any back
+        decoded = device.multiget_decode(
+            token_lists, pad_tokens=self.bucket_caps,
+            pad_batch=self.batch_size, use_pallas=self.use_pallas,
+            rows=counts, on_batch=record)
+        for i, val in zip(misses, decoded):
+            results[i] = val
 
     def warm_decode(self) -> None:
         """Compile the device decode at every bucket shape, by one empty

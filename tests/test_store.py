@@ -425,6 +425,11 @@ def test_copy_counters_count_what_each_decode_path_copies(titles, store16):
     dev = store._device
     assert (snap["h2d_bytes"], snap["d2h_bytes"]) == (dev.h2d_bytes,
                                                       dev.d2h_bytes)
+    # bytes come back, one per decoded byte of the [B, 16 T] output: a
+    # quarter of the int32 words the kernel writes; no lengths
+    assert snap["d2h_bytes"] == sum(
+        n * b * 16 * t for b, t in
+        (map(int, k.split("x")) for k in shapes) for n in [shapes[f"{b}x{t}"]])
     # one batch by hand: the counts are the nbytes of the arrays copied
     tok, n_tok = pack_token_matrix(
         [store16.segments.string_tokens(i) for i in ids[:5]], pad_batch=8)
@@ -433,6 +438,6 @@ def test_copy_counters_count_what_each_decode_path_copies(titles, store16):
     out, olen = onpair_decode.decode_compact(
         jnp.asarray(tok), jnp.asarray(n_tok), dev.dd.mat16, dev.dd.lens,
         16 * tok.shape[1])
+    assert out.dtype == np.uint8
     assert dev.h2d_bytes - h0 == tok.nbytes + n_tok.nbytes
-    assert dev.d2h_bytes - d0 == (np.asarray(out).nbytes
-                                  + np.asarray(olen).nbytes)
+    assert dev.d2h_bytes - d0 == np.asarray(out).nbytes == 8 * 16 * tok.shape[1]

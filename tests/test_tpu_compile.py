@@ -135,3 +135,19 @@ def test_decode_compact_compiles_at_the_dictionary_vmem_budget(one_chip, T):
         _spec(one_chip, (256, T)), _spec(one_chip, (256,)),
         _spec(one_chip, (rows, 16)), _spec(one_chip, (rows,)),
         max_out=16 * T))
+
+
+@pytest.mark.parametrize("rows, T", [(N_ENTRIES, 16), (N_CHAINED_ROWS, ROW_CAP)],
+                         ids=["onpair16", "chained"])
+def test_decode_compact_reads_back_bytes(one_chip, rows, T):
+    """The multiget kernel with its output cast to bytes, at an OnPair16
+    serving bucket and a chained row bucket: the cast compiles with the
+    kernel, and the copy back is uint8[256, 16 T], not int32 words."""
+    lowered = onpair_decode.decode_compact.lower(
+        _spec(one_chip, (256, T)), _spec(one_chip, (256,)),
+        _spec(one_chip, (rows, 16)), _spec(one_chip, (rows,)),
+        max_out=16 * T)
+    _assert_kernel(lowered)
+    out, olen = lowered.out_info
+    assert (out.shape, out.dtype) == ((256, 16 * T), jnp.uint8)
+    assert (olen.shape, olen.dtype) == ((256,), jnp.int32)
